@@ -400,9 +400,7 @@ def bench_startup(emit, steps=2):
     params = model.init(jax.random.PRNGKey(0))
 
     tmp = tempfile.mkdtemp(prefix="bench_startup_")
-    cache_dir = os.path.join(tmp, "xla")
-    prev_cache = jax.config.jax_compilation_cache_dir
-    perf.enable_persistent_cache(cache_dir)
+    cache_dir = perf.enable_persistent_cache()
     try:
         def train_once():
             t0 = time.perf_counter()
@@ -455,11 +453,6 @@ def bench_startup(emit, steps=2):
             assert sst_w["compilations"] == 0 and sst_w["aot_loads"] >= 1, (
                 f"warm ServeSession recompiled: {sst_w}")
     finally:
-        # the bench repointed the process-global cache config; restore
-        if prev_cache:
-            perf.enable_persistent_cache(prev_cache)
-        else:
-            perf.disable_persistent_cache()
         shutil.rmtree(tmp, ignore_errors=True)
 
 

@@ -5,11 +5,10 @@ Supported lane widths are ``SUPPORTED_BITS`` = (2, 3, 4, 6, 8, 16). The
 odd widths pack across byte boundaries in *groups*: ``lcm(bits, 8)`` bits
 of codes become whole bytes, so a group of ``group_codes(bits)`` codes
 maps to ``group_nbytes(bits)`` bytes (3-bit: 8 codes -> 3 bytes; 6-bit:
-4 codes -> 3 bytes). For the widths that divide 8 this degenerates to the
-historical ``repro.core.packing`` layout byte-for-byte (little-endian
-shifts within the byte, signed codes biased by ``2^(bits-1)``); 8-bit
-lanes are the two's-complement int8 view, 16-bit lanes the little-endian
-int16 view.
+4 codes -> 3 bytes). The members of a group are ``PLANE`` = 128 codes
+apart (one vreg of lanes), see :data:`PLANE`; within a group, codes are
+biased by ``2^(bits-1)`` below 8 bits (two's complement at 8 and 16)
+and shifted in little-endian order. 8-bit lanes are the int8 view.
 
 Everything here is pure jnp arithmetic (no dtype views), so the *same*
 functions run inside the fused Pallas kernel bodies
@@ -59,8 +58,69 @@ def lane_bits_for(max_abs_code: int) -> int:
 
 def _bias(bits: int) -> int:
     # <8-bit lanes use the historical biased-unsigned layout; 8/16-bit
-    # lanes are two's complement (byte-identical to an int8/int16 view).
+    # lanes are two's complement (the low bits of an int8/int16 view).
     return (1 << (bits - 1)) if bits < 8 else 0
+
+
+# Codes of one packing group sit PLANE apart, not side by side: a block
+# of ``group_codes * PLANE`` codes is read as ``group_codes`` planes of
+# PLANE lanes, and writes ``group_nbytes`` byte planes of PLANE lanes.
+# Packing then only slices whole 128-lane vregs - no lane-strided access,
+# which the TPU's kernel compiler cannot lower. A row's last, partial
+# block uses planes of ``w < PLANE`` lanes, so the payload stays exactly
+# ``payload_nbytes`` bytes.
+PLANE = 128
+
+
+def block_codes(bits: int) -> int:
+    """Codes in one whole packing block (``group_codes`` planes)."""
+    return group_codes(bits) * PLANE
+
+
+def block_nbytes(bits: int) -> int:
+    """Payload bytes of one whole packing block."""
+    return group_nbytes(bits) * PLANE
+
+
+def _pack_block(u, bits: int, w: int):
+    """(..., g*w) lane values -> (..., nb*w) byte values (int32)."""
+    g, nb = group_codes(bits), group_nbytes(bits)
+    val = u[..., 0:w]
+    for j in range(1, g):  # <= 24 bits per group, fits int32
+        val = val | (u[..., j * w:(j + 1) * w] << (j * bits))
+    if nb == 1:
+        return val & 0xFF
+    return jnp.concatenate([(val >> (8 * b)) & 0xFF for b in range(nb)],
+                           axis=-1)
+
+
+def _unpack_block(p, bits: int, w: int):
+    """(..., nb*w) byte values -> (..., g*w) lane values (int32)."""
+    g, nb = group_codes(bits), group_nbytes(bits)
+    val = p[..., 0:w]
+    for b in range(1, nb):
+        val = val | (p[..., b * w:(b + 1) * w] << (8 * b))
+    if g == 1:
+        return val
+    mask = (1 << bits) - 1
+    return jnp.concatenate([(val >> (j * bits)) & mask for j in range(g)],
+                           axis=-1)
+
+
+def _by_blocks(x, fn, per_in: int):
+    """Apply ``fn(block, w)`` to a row's whole blocks (``per_in`` input
+    lanes each) and to its tail block; concatenate the results."""
+    rows, L = x.shape
+    full, rest = divmod(L, per_in)
+    if full == 1 and rest == 0:          # one block per row (kernel tiles)
+        return fn(x, PLANE)
+    parts = []
+    if full:
+        blk = fn(x[:, :full * per_in].reshape(rows, full, per_in), PLANE)
+        parts.append(blk.reshape(rows, -1))
+    if rest:
+        parts.append(fn(x[:, full * per_in:], rest * PLANE // per_in))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
 
 
 def pack_lanes(codes2d: jax.Array, bits: int) -> jax.Array:
@@ -68,45 +128,101 @@ def pack_lanes(codes2d: jax.Array, bits: int) -> jax.Array:
     independently. L must be a multiple of group_codes(bits)."""
     if bits not in SUPPORTED_BITS:
         raise ValueError(f"bits={bits} not in {SUPPORTED_BITS}")
-    rows, L = codes2d.shape
-    g, nb = group_codes(bits), group_nbytes(bits)
-    assert L % g == 0, (L, g)
-    u = codes2d.astype(jnp.int32) + _bias(bits)
-    if bits == 16:
-        u = u & 0xFFFF
-        out = jnp.stack([u & 0xFF, (u >> 8) & 0xFF], axis=-1)
-        return out.reshape(rows, 2 * L).astype(jnp.uint8)
-    if bits == 8:
-        return (u & 0xFF).astype(jnp.uint8)
-    grp = u.reshape(rows, L // g, g)
-    val = jnp.zeros((rows, L // g), jnp.int32)
-    for j in range(g):  # <= 24 bits per group, fits int32
-        val = val | (grp[:, :, j] << (j * bits))
-    out = jnp.stack([(val >> (8 * b)) & 0xFF for b in range(nb)], axis=-1)
-    return out.reshape(rows, (L // g) * nb).astype(jnp.uint8)
+    L = codes2d.shape[1]
+    assert L % group_codes(bits) == 0, (L, bits)
+    u = (codes2d.astype(jnp.int32) + _bias(bits)) & ((1 << bits) - 1)
+    out = _by_blocks(u, lambda b, w: _pack_block(b, bits, w),
+                     block_codes(bits))
+    return out.astype(jnp.uint8)
 
 
 def unpack_lanes(payload2d: jax.Array, bits: int, L: int) -> jax.Array:
     """Inverse of pack_lanes -> (R, L) codes (int8, or int16 for 16-bit
-    lanes)."""
+    lanes). The payload may hold more codes than L (rows padded to whole
+    blocks); the first L are returned."""
     if bits not in SUPPORTED_BITS:
         raise ValueError(f"bits={bits} not in {SUPPORTED_BITS}")
-    rows = payload2d.shape[0]
-    g, nb = group_codes(bits), group_nbytes(bits)
-    u = payload2d.astype(jnp.int32)
-    if bits == 16:
-        pair = u.reshape(rows, L, 2)
-        val = pair[:, :, 0] | (pair[:, :, 1] << 8)
-        return (((val + 0x8000) & 0xFFFF) - 0x8000).astype(jnp.int16)
-    if bits == 8:
-        return (((u + 0x80) & 0xFF) - 0x80).astype(jnp.int8)
-    grp = u.reshape(rows, L // g, nb)
-    val = jnp.zeros((rows, L // g), jnp.int32)
-    for b in range(nb):
-        val = val | (grp[:, :, b] << (8 * b))
-    mask = (1 << bits) - 1
-    cols = [((val >> (j * bits)) & mask) - _bias(bits) for j in range(g)]
-    return jnp.stack(cols, axis=-1).reshape(rows, L).astype(jnp.int8)
+    u = _by_blocks(payload2d.astype(jnp.int32),
+                   lambda b, w: _unpack_block(b, bits, w), block_nbytes(bits))
+    if u.shape[1] != L:
+        u = u[:, :L]
+    if bits < 8:
+        codes = u - _bias(bits)
+    else:
+        half = 1 << (bits - 1)
+        codes = ((u + half) & ((1 << bits) - 1)) - half
+    return codes.astype(jnp.int16 if bits == 16 else jnp.int8)
+
+
+# ---------------------------------------------------------------------------
+# kernel tile views: the fused codec kernels see every row as whole
+# blocks. A tail block's g planes of w lanes are spread to PLANE-lane
+# planes (zero-filled) on the way in and gathered back on the way out,
+# so a kernel that packs whole blocks writes exactly pack_lanes' bytes.
+# ---------------------------------------------------------------------------
+
+def _split_tail(L: int, bits: int):
+    g = group_codes(bits)
+    lg = -(-L // g) * g
+    full, rest = divmod(lg, block_codes(bits))
+    return lg, full, rest // g
+
+
+def to_blocks(x_rows: jax.Array, bits: int, nblk: int) -> jax.Array:
+    """(R, L) values -> (R, nblk * block_codes) in whole-block layout."""
+    R, L = x_rows.shape
+    g, blk = group_codes(bits), block_codes(bits)
+    lg, full, w = _split_tail(L, bits)
+    x = jnp.pad(x_rows, ((0, 0), (0, lg - L))) if lg != L else x_rows
+    parts = [x[:, :full * blk]]
+    if w:
+        t = x[:, full * blk:].reshape(R, g, w)
+        parts.append(jnp.pad(t, ((0, 0), (0, 0), (0, PLANE - w)))
+                     .reshape(R, blk))
+    used = full + (1 if w else 0)
+    parts.append(jnp.zeros((R, (nblk - used) * blk), x.dtype))
+    return jnp.concatenate(parts, axis=-1)
+
+
+def from_blocks(y: jax.Array, L: int, bits: int) -> jax.Array:
+    """Inverse of :func:`to_blocks` on decoded values -> (R, L)."""
+    R = y.shape[0]
+    g, blk = group_codes(bits), block_codes(bits)
+    lg, full, w = _split_tail(L, bits)
+    parts = [y[:, :full * blk]]
+    if w:
+        t = y[:, full * blk:(full + 1) * blk].reshape(R, g, PLANE)
+        parts.append(t[:, :, :w].reshape(R, g * w))
+    return jnp.concatenate(parts, axis=-1)[:, :L]
+
+
+def payload_from_blocks(p: jax.Array, L: int, bits: int) -> jax.Array:
+    """Whole-block payload rows of L codes -> (R, payload_nbytes(L))."""
+    R = p.shape[0]
+    nb, bb = group_nbytes(bits), block_nbytes(bits)
+    _, full, w = _split_tail(L, bits)
+    parts = [p[:, :full * bb]]
+    if w:
+        t = p[:, full * bb:(full + 1) * bb].reshape(R, nb, PLANE)
+        parts.append(t[:, :, :w].reshape(R, nb * w))
+    return jnp.concatenate(parts, axis=-1)
+
+
+def payload_to_blocks(p: jax.Array, L: int, bits: int,
+                      nblk: int) -> jax.Array:
+    """Inverse of :func:`payload_from_blocks`: (R, payload_nbytes(L))
+    -> (R, nblk * block_nbytes) whole-block payload rows."""
+    R = p.shape[0]
+    nb, bb = group_nbytes(bits), block_nbytes(bits)
+    _, full, w = _split_tail(L, bits)
+    parts = [p[:, :full * bb]]
+    if w:
+        t = p[:, full * bb:].reshape(R, nb, w)
+        parts.append(jnp.pad(t, ((0, 0), (0, 0), (0, PLANE - w)))
+                     .reshape(R, bb))
+    used = full + (1 if w else 0)
+    parts.append(jnp.zeros((R, (nblk - used) * bb), p.dtype))
+    return jnp.concatenate(parts, axis=-1)
 
 
 # ---------------------------------------------------------------------------
@@ -125,9 +241,7 @@ def pack_flat(codes: jax.Array, bits: int) -> jax.Array:
 
 def unpack_flat(payload: jax.Array, bits: int, numel: int) -> jax.Array:
     """Inverse of pack_flat -> (numel,) codes."""
-    g = group_codes(bits)
-    padded = -(-numel // g) * g
-    return unpack_lanes(payload.reshape(1, -1), bits, padded)[0, :numel]
+    return unpack_lanes(payload.reshape(1, -1), bits, numel)[0]
 
 
 def pack_rows(codes_rows: jax.Array, bits: int) -> jax.Array:
@@ -143,9 +257,7 @@ def pack_rows(codes_rows: jax.Array, bits: int) -> jax.Array:
 
 def unpack_rows(payload_rows: jax.Array, bits: int, c: int) -> jax.Array:
     """Inverse of pack_rows -> (n_rows, c) codes."""
-    g = group_codes(bits)
-    padded = -(-c // g) * g
-    return unpack_lanes(payload_rows, bits, padded)[:, :c]
+    return unpack_lanes(payload_rows, bits, c)
 
 
 def pad_rows(x: jax.Array, n_rows: int) -> jax.Array:

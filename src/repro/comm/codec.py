@@ -117,21 +117,21 @@ class WireBuffer:
 
 
 # ---------------------------------------------------------------------------
-# tiling helpers (pad to the fused kernels' (R, LANES_IN[bits]) layout)
+# tiling helpers: every wire row becomes whole packing blocks, one per
+# kernel row (``repro.comm.bits.to_blocks``), in a multiple of the
+# kernels' rows per grid step
 # ---------------------------------------------------------------------------
 
-def _tile_rows(n: int, bits: int) -> int:
-    """Rows of the (R, lanes_in) tiling covering n elements."""
-    li = K.lanes_in(bits)
+def _row_blocks(c: int, bits: int) -> int:
+    """Kernel rows (packing blocks) per wire row of c elements."""
     er = K.enc_rows()
-    return -(-n // (er * li)) * er
+    return -(-c // (er * K.lanes_in(bits))) * er
 
 
-def _to_tiles(flat: jax.Array, bits: int) -> jax.Array:
-    li = K.lanes_in(bits)
-    rows = _tile_rows(flat.shape[0], bits)
-    pad = rows * li - flat.shape[0]
-    return jnp.pad(flat, (0, pad)).reshape(rows, li)
+def _kernel_rows(rows: jax.Array, bits: int) -> jax.Array:
+    """(R, c) values -> (R * _row_blocks(c), lanes_in) kernel tiles."""
+    nblk = _row_blocks(rows.shape[1], bits)
+    return B.to_blocks(rows, bits, nblk).reshape(-1, K.lanes_in(bits))
 
 
 # ---------------------------------------------------------------------------
@@ -247,14 +247,15 @@ class Codec:
             payload = B.pack_flat(codes, self.bits)
             return WireBuffer(payload=payload, scale=scale,
                               spec=self.spec, shape=shape)
-        x2d = _to_tiles(flat, self.bits)
-        u2d = _to_tiles(u, self.bits) if u is not None else None
+        x2d = _kernel_rows(flat[None], self.bits)
+        u2d = _kernel_rows(u[None], self.bits) if u is not None else None
         payload2d, scale = K.encode_pallas(
             x2d, self.kind, self.bits, self.k,
             scale=(None if self.static_scale is None
                    else jnp.float32(self.static_scale)),
             u2d=u2d, clip_abs=self.clip_abs, interpret=_interpret())
-        payload = payload2d.reshape(-1)[:self.payload_nbytes(n)]
+        payload = B.payload_from_blocks(payload2d.reshape(1, -1), n,
+                                        self.bits)[0]
         return WireBuffer(payload=payload, scale=scale, spec=self.spec,
                           shape=shape)
 
@@ -265,14 +266,14 @@ class Codec:
             codes = B.unpack_flat(wb.payload, self.bits, n)
             return self.dequantize(codes, wb.scale).astype(
                 out_dtype).reshape(wb.shape)
-        lo = K.lanes_out(self.bits)
-        rows = _tile_rows(n, self.bits)
-        pad = rows * lo - wb.payload.shape[0]
-        p2d = jnp.pad(wb.payload, (0, pad)).reshape(rows, lo)
-        out = K.decode_pallas(p2d, wb.scale, self.kind, self.bits, self.k,
+        p2d = B.payload_to_blocks(wb.payload[None], n, self.bits,
+                                  _row_blocks(n, self.bits))
+        out = K.decode_pallas(p2d.reshape(-1, K.lanes_out(self.bits)),
+                              wb.scale, self.kind, self.bits, self.k,
                               out_dtype=out_dtype, lut=self.dequant_lut(),
                               interpret=_interpret())
-        return out.reshape(-1)[:n].reshape(wb.shape)
+        return B.from_blocks(out.reshape(1, -1), n,
+                             self.bits)[0].reshape(wb.shape)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -426,16 +427,14 @@ class BlockwiseCodec(Codec):
         if resolve_backend(backend, n) == "jnp":
             codes, scales = grids.blockwise_quantize(x2d)
             codes = jax.lax.optimization_barrier(codes)  # see Codec
-            payload = B.pack_flat(codes, self.bits)[:self.payload_nbytes(n)]
-            return WireBuffer(payload=payload, scale=scales,
-                              spec=self.spec, shape=shape)
-        rpad = (-nb) % K.BLOCKWISE_ROWS
-        x2dp = jnp.pad(x2d, ((0, rpad), (0, 0)))
-        payload2d, scales = K.encode_blockwise_pallas(
-            x2dp, bits=self.bits, interpret=_interpret())
-        payload = payload2d.reshape(-1)[:self.payload_nbytes(n)]
-        return WireBuffer(payload=payload, scale=scales[:nb],
-                          spec=self.spec, shape=shape)
+        else:
+            rpad = (-nb) % K.BLOCKWISE_ROWS
+            codes, scales = K.blockwise_quantize_pallas(
+                jnp.pad(x2d, ((0, rpad), (0, 0))), interpret=_interpret())
+            codes, scales = codes[:nb], scales[:nb]
+        payload = B.pack_flat(codes.reshape(-1)[:n], self.bits)
+        return WireBuffer(payload=payload, scale=scales, spec=self.spec,
+                          shape=shape)
 
     def _decode_impl(self, wb: WireBuffer, *, backend=None,
                      out_dtype=jnp.float32) -> jax.Array:
@@ -553,14 +552,6 @@ CODEC_NAMES = ("identity", "log", "uniform", "uniform_amax", "terngrad",
 # row-chunked wire entry points (the layout the dist collectives move)
 # ---------------------------------------------------------------------------
 
-def _rows_tiling(c: int, bits: int):
-    """Per-row padded length and tile count for the fused kernels."""
-    li = K.lanes_in(bits)
-    er = K.enc_rows()
-    t = -(-c // (er * li))                   # (er, li) tiles per row
-    return t * er * li, t * er
-
-
 def encode_rows(x: jax.Array, codec: Codec, n_rows: int, *, key=None,
                 backend: Optional[str] = None):
     """Fused encode into worker-ownership rows: flat x -> ``(n_rows,
@@ -585,22 +576,16 @@ def _encode_rows_jit(x, key, *, codec, n_rows, backend):
         codes = codec.quantize(flat, scale, u=u)
         codes = jax.lax.optimization_barrier(codes)  # see _encode_impl
         return B.pack_rows(B.pad_rows(codes, n_rows), codec.bits), scale
-    lrow, rrow = _rows_tiling(c, codec.bits)
-    rows_f = B.pad_rows(flat, n_rows)
-    rows_f = jnp.pad(rows_f, ((0, 0), (0, lrow - c)))
-    x2d = rows_f.reshape(n_rows * rrow, K.lanes_in(codec.bits))
-    if u is not None:
-        ru = jnp.pad(B.pad_rows(u, n_rows), ((0, 0), (0, lrow - c)))
-        u2d = ru.reshape(n_rows * rrow, K.lanes_in(codec.bits))
-    else:
-        u2d = None
+    x2d = _kernel_rows(B.pad_rows(flat, n_rows), codec.bits)
+    u2d = (_kernel_rows(B.pad_rows(u, n_rows), codec.bits)
+           if u is not None else None)
     payload2d, scale = K.encode_pallas(
         x2d, codec.kind, codec.bits, codec.k,
         scale=(None if codec.static_scale is None
                else jnp.float32(codec.static_scale)),
         u2d=u2d, clip_abs=codec.clip_abs, interpret=_interpret())
-    payload = payload2d.reshape(n_rows, -1)[:, :codec.payload_nbytes(c)]
-    return payload, scale
+    return B.payload_from_blocks(payload2d.reshape(n_rows, -1), c,
+                                 codec.bits), scale
 
 
 def encode_rows_ef(x: jax.Array, scale, codec: Codec, n_rows: int, *,
@@ -626,15 +611,15 @@ def _encode_rows_ef_jit(x, scale, *, codec, n_rows, backend):
         e_new = flat - codec.dequantize(codes, scale)
         return (B.pack_rows(B.pad_rows(codes, n_rows), codec.bits),
                 e_new.reshape(shape))
-    lrow, rrow = _rows_tiling(c, codec.bits)
-    rows_f = jnp.pad(B.pad_rows(flat, n_rows), ((0, 0), (0, lrow - c)))
-    x2d = rows_f.reshape(n_rows * rrow, K.lanes_in(codec.bits))
+    x2d = _kernel_rows(B.pad_rows(flat, n_rows), codec.bits)
     payload2d, e2d = K.ef_encode_pallas(x2d, scale, codec.kind, codec.bits,
                                         codec.k, clip_abs=codec.clip_abs,
                                         lut=codec.dequant_lut(),
                                         interpret=_interpret())
-    payload = payload2d.reshape(n_rows, -1)[:, :codec.payload_nbytes(c)]
-    e_new = e2d.reshape(n_rows, lrow)[:, :c].reshape(-1)[:n]
+    payload = B.payload_from_blocks(payload2d.reshape(n_rows, -1), c,
+                                    codec.bits)
+    e_new = B.from_blocks(e2d.reshape(n_rows, -1), c,
+                          codec.bits).reshape(-1)[:n]
     return payload, e_new.reshape(shape)
 
 
@@ -657,15 +642,11 @@ def _decode_rows_jit(payload_rows, scales, *, codec, c, backend, out_dtype):
     if resolve_backend(backend, n_rows * c) == "jnp":
         codes = B.unpack_rows(payload_rows, codec.bits, c)
         return codec.dequantize(codes, scales[:, None]).astype(out_dtype)
-    lo = K.lanes_out(codec.bits)
-    li = K.lanes_in(codec.bits)
-    lrow, rrow = _rows_tiling(c, codec.bits)
-    brow = rrow * lo                                  # payload bytes/row
-    p = jnp.pad(payload_rows,
-                ((0, 0), (0, brow - payload_rows.shape[1])))
-    p2d = p.reshape(n_rows * rrow, lo)
-    out = K.decode_pallas(p2d, scales, codec.kind, codec.bits, codec.k,
-                          tiles_per_scale=rrow // K.enc_rows(),
+    nblk = _row_blocks(c, codec.bits)
+    p2d = B.payload_to_blocks(payload_rows, c, codec.bits, nblk)
+    out = K.decode_pallas(p2d.reshape(-1, K.lanes_out(codec.bits)), scales,
+                          codec.kind, codec.bits, codec.k,
+                          tiles_per_scale=nblk // K.enc_rows(),
                           out_dtype=out_dtype, lut=codec.dequant_lut(),
                           interpret=_interpret())
-    return out.reshape(n_rows, rrow * li)[:, :c]
+    return B.from_blocks(out.reshape(n_rows, -1), c, codec.bits)

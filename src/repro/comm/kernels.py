@@ -23,9 +23,11 @@ Every kernel body calls the canonical math in ``repro.opt.grids`` and
 to the jnp reference backend by construction (asserted across all lane
 widths by ``tests/test_comm_codecs.py``).
 
-Lane geometry: the input tile's lane count ``LANES_IN[bits]`` is chosen
-so the packed output tile is a whole number of 128-lane VREGs (e.g.
-3-bit lanes read (rows, 1024) floats and write (rows, 384) bytes).
+Lane geometry: every tile row is one whole packing block
+(``repro.comm.bits.block_codes``): ``LANES_IN[bits]`` codes read as
+planes of 128 lanes, packed to byte planes of 128 lanes (e.g. 3-bit
+lanes read (rows, 1024) floats and write (rows, 384) bytes). Packing
+then only slices whole vregs, which is what the TPU compiler lowers.
 
 The historical per-op kernels (separate amax / quantize / dequantize
 passes) also live here now; ``repro.kernels.quantize`` and
@@ -51,7 +53,10 @@ LANES = 128
 # sub-tile tensors don't over-pad) and input lanes per lane width (the
 # packed output tile is then a whole number of 128-lane VREGs).
 ENC_ROWS = 32
-LANES_IN = {2: 512, 3: 1024, 4: 256, 6: 512, 8: 128, 16: 128}
+LANES_IN = {b: B.block_codes(b) for b in B.SUPPORTED_BITS}
+
+# blockwise sign codes: block rows per grid step
+BLOCKWISE_ROWS = 8
 
 # per-backend tile-width override (autotuning hook): maps a
 # ``jax.default_backend()`` name to the rows-per-grid-step the fused
@@ -86,6 +91,28 @@ def lanes_in(bits: int) -> int:
 
 def lanes_out(bits: int) -> int:
     return LANES_IN[bits] * bits // 8
+
+
+def smem():
+    """Whole-array SMEM placement: scalar operands and results (scales,
+    hyper-parameters, amax) live in scalar memory on TPU - a vector
+    memory block cannot hold or store a scalar."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def lut_dequant(codes, scale, lut_ref):
+    """Table dequant inside a kernel: ``lut[code + n/2] * scale`` with
+    the table in SMEM. A select chain over the 2^bits scalars replaces
+    the vector gather that TPU kernels cannot do; reading the entries
+    in ascending order reproduces ``jnp.take(..., mode="clip")``, so the
+    result equals :func:`repro.opt.grids.log_dequantize_lut` bit for
+    bit."""
+    n = lut_ref.shape[0]
+    idx = codes.astype(jnp.int32) + n // 2
+    val = jnp.full(codes.shape, lut_ref[0], jnp.float32)
+    for j in range(1, n):
+        val = jnp.where(idx >= j, lut_ref[j], val)
+    return val * scale
 
 
 # ---------------------------------------------------------------------------
@@ -129,9 +156,18 @@ def _dequant(codes, scale, *, kind: str, k: int, lut=None):
 # fused encode (single launch)
 # ---------------------------------------------------------------------------
 
-def _encode2_body(x_ref, payload_ref, scale_ref, acc_ref, *, kind, bits,
-                  k, clip_abs):
+def _kdequant(codes, scale, *, kind, k, lut_ref):
+    if lut_ref is not None:
+        return lut_dequant(codes, scale, lut_ref)
+    return _dequant(codes, scale, kind=kind, k=k)
+
+
+def _encode2_body(*refs, kind, bits, k, clip_abs):
     """Two-phase: (0, i) amax partials -> SMEM; (1, i) quantize + pack."""
+    if kind == "ternary":
+        x_ref, u_ref, payload_ref, scale_ref, acc_ref = refs
+    else:
+        (x_ref, payload_ref, scale_ref, acc_ref), u_ref = refs, None
     ph = pl.program_id(0)
     i = pl.program_id(1)
 
@@ -156,38 +192,8 @@ def _encode2_body(x_ref, payload_ref, scale_ref, acc_ref, *, kind, bits,
         def _():
             scale_ref[0] = scale
 
-        codes = _quant(x_ref[...], scale, None, kind=kind, k=k,
-                       clip_abs=clip_abs)
-        payload_ref[...] = B.pack_lanes(codes, bits)
-
-
-def _encode2_ternary_body(x_ref, u_ref, payload_ref, scale_ref, acc_ref,
-                          *, bits, clip_abs):
-    ph = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(ph == 0)
-    def _():
-        part = grids.block_amax(x_ref[...])
-
-        @pl.when(i == 0)
-        def _():
-            acc_ref[0] = part
-
-        @pl.when(i > 0)
-        def _():
-            acc_ref[0] = jnp.maximum(acc_ref[0], part)
-
-    @pl.when(ph == 1)
-    def _():
-        amax = acc_ref[0]
-        scale = jnp.where(amax > 0, amax, 1.0).astype(jnp.float32)
-
-        @pl.when(i == 0)
-        def _():
-            scale_ref[0] = scale
-
-        codes = _quant(x_ref[...], scale, u_ref[...], kind="ternary", k=0,
+        u = u_ref[...] if u_ref is not None else None
+        codes = _quant(x_ref[...], scale, u, kind=kind, k=k,
                        clip_abs=clip_abs)
         payload_ref[...] = B.pack_lanes(codes, bits)
 
@@ -205,7 +211,8 @@ def encode_pallas(x2d: jax.Array, kind: str, bits: int, k: int, *,
                   interpret: bool):
     """Fused amax+quantize+pack, ONE ``pallas_call``.
 
-    x2d: (R, LANES_IN[bits]) f32, R a multiple of ENC_ROWS. Returns
+    x2d: (R, LANES_IN[bits]) f32, R a multiple of ENC_ROWS, each row one
+    whole packing block (``repro.comm.bits.to_blocks``). Returns
     ``(payload2d uint8 (R, lanes_out), scale ())``; with ``scale=`` given
     the amax phase is skipped and the same scale is returned.
     """
@@ -215,7 +222,6 @@ def encode_pallas(x2d: jax.Array, kind: str, bits: int, k: int, *,
     assert x2d.shape[1] == li and rows % er == 0, (x2d.shape, bits)
     nb = rows // er
     xblk = pl.BlockSpec((er, li), lambda p, i: (i, 0))
-    pblk = pl.BlockSpec((er, lo), lambda p, i: (i, 0))
     payload_shape = jax.ShapeDtypeStruct((rows, lo), jnp.uint8)
 
     if scale is not None:
@@ -224,33 +230,30 @@ def encode_pallas(x2d: jax.Array, kind: str, bits: int, k: int, *,
             functools.partial(_encode1_body, kind=kind, bits=bits, k=k,
                               clip_abs=clip_abs),
             grid=(1, nb),
-            in_specs=[xblk, pl.BlockSpec((1,), lambda p, i: (0,))],
-            out_specs=pblk,
+            in_specs=[xblk, smem()],
+            out_specs=pl.BlockSpec((er, lo), lambda p, i: (i, 0)),
             out_shape=payload_shape,
             interpret=interpret,
+            name="codec_encode",
         )(x2d, scale.reshape(1))
         return payload, scale
 
-    sblk = pl.BlockSpec((1,), lambda p, i: (0,))
+    operands, in_specs = (x2d,), [xblk]
     if kind == "ternary":
-        body = functools.partial(_encode2_ternary_body, bits=bits,
-                                 clip_abs=clip_abs)
-        operands = (x2d, u2d)
-        in_specs = [xblk, pl.BlockSpec((er, li), lambda p, i: (i, 0))]
-    else:
-        body = functools.partial(_encode2_body, kind=kind, bits=bits, k=k,
-                                 clip_abs=clip_abs)
-        operands = (x2d,)
-        in_specs = [xblk]
+        operands, in_specs = (x2d, u2d), [xblk, xblk]
+    # phase 0 parks the payload window on block 0 (i * p), so no block
+    # is written back before phase 1 has filled it
     payload, scale_out = pl.pallas_call(
-        body,
+        functools.partial(_encode2_body, kind=kind, bits=bits, k=k,
+                          clip_abs=clip_abs),
         grid=(2, nb),
         in_specs=in_specs,
-        out_specs=[pblk, sblk],
+        out_specs=[pl.BlockSpec((er, lo), lambda p, i: (i * p, 0)), smem()],
         out_shape=[payload_shape,
                    jax.ShapeDtypeStruct((1,), jnp.float32)],
         scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
         interpret=interpret,
+        name="codec_encode",
     )(*operands)
     return payload, scale_out[0]
 
@@ -259,28 +262,20 @@ def encode_pallas(x2d: jax.Array, kind: str, bits: int, k: int, *,
 # fused decode (single launch)
 # ---------------------------------------------------------------------------
 
-def _decode_body(payload_ref, scale_ref, o_ref, *, kind, bits, k,
-                 out_dtype):
-    li = o_ref.shape[-1]
-    codes = B.unpack_lanes(payload_ref[...], bits, li)
-    o_ref[...] = _dequant(codes, scale_ref[0], kind=kind,
-                          k=k).astype(out_dtype)
-
-
-def _decode_lut_body(payload_ref, scale_ref, lut_ref, o_ref, *, kind,
-                     bits, k, out_dtype):
-    """Decode with the dequant table resident in SMEM: unpack, then one
-    gather per element instead of re-evaluating exp2 on every
-    lane-strided code (the 0.23x fused-log-decode regression)."""
-    li = o_ref.shape[-1]
-    codes = B.unpack_lanes(payload_ref[...], bits, li)
-    o_ref[...] = _dequant(codes, scale_ref[0], kind=kind, k=k,
-                          lut=lut_ref[...]).astype(out_dtype)
-
-
-def _lut_spec():
-    """Whole-table SMEM placement for a (2^bits,) f32 dequant table."""
-    return pl.BlockSpec(memory_space=pltpu.SMEM)
+def _decode_body(*refs, kind, bits, k, out_dtype, tiles_per_scale,
+                 has_lut):
+    """Unpack, then dequantize; with a table (log grid) the dequant is a
+    select over SMEM scalars instead of an exp2 per lane-strided code
+    (the 0.23x fused-log-decode regression)."""
+    if has_lut:
+        payload_ref, scale_ref, lut_ref, o_ref = refs
+    else:
+        (payload_ref, scale_ref, o_ref), lut_ref = refs, None
+    t = tiles_per_scale
+    s = scale_ref[pl.program_id(0) // t] if t else scale_ref[0]
+    codes = B.unpack_lanes(payload_ref[...], bits, o_ref.shape[-1])
+    o_ref[...] = _kdequant(codes, s, kind=kind, k=k,
+                           lut_ref=lut_ref).astype(out_dtype)
 
 
 def decode_pallas(payload2d: jax.Array, scales: jax.Array, kind: str,
@@ -293,64 +288,48 @@ def decode_pallas(payload2d: jax.Array, scales: jax.Array, kind: str,
     (per-tensor) or a (n_rows,) vector with ``tiles_per_scale`` grid
     steps per wire row (the per-source-worker scales of the dist
     channels). ``lut`` (log grid only) is the (2^bits,) scale-1 dequant
-    table from ``grids.log_dequant_table``; it rides in SMEM and turns
-    the dequant into a gather.
+    table from ``grids.log_dequant_table``; it rides in SMEM.
     """
     rows = payload2d.shape[0]
     er = enc_rows()
     li, lo = lanes_in(bits), lanes_out(bits)
     assert payload2d.shape[1] == lo and rows % er == 0
-    nb = rows // er
     scales = jnp.asarray(scales, jnp.float32).reshape(-1)
-    if tiles_per_scale:
-        t = tiles_per_scale
-        sspec = pl.BlockSpec((1,), lambda i: (i // t,))
-    else:
-        sspec = pl.BlockSpec((1,), lambda i: (0,))
+    operands = [payload2d, scales]
+    in_specs = [pl.BlockSpec((er, lo), lambda i: (i, 0)), smem()]
     if lut is not None:
-        return pl.pallas_call(
-            functools.partial(_decode_lut_body, kind=kind, bits=bits, k=k,
-                              out_dtype=out_dtype),
-            grid=(nb,),
-            in_specs=[pl.BlockSpec((er, lo), lambda i: (i, 0)), sspec,
-                      _lut_spec()],
-            out_specs=pl.BlockSpec((er, li), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((rows, li), out_dtype),
-            interpret=interpret,
-        )(payload2d, scales, jnp.asarray(lut, jnp.float32))
+        operands.append(jnp.asarray(lut, jnp.float32))
+        in_specs.append(smem())
     return pl.pallas_call(
         functools.partial(_decode_body, kind=kind, bits=bits, k=k,
-                          out_dtype=out_dtype),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((er, lo), lambda i: (i, 0)), sspec],
+                          out_dtype=out_dtype,
+                          tiles_per_scale=tiles_per_scale,
+                          has_lut=lut is not None),
+        grid=(rows // er,),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((er, li), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, li), out_dtype),
         interpret=interpret,
-    )(payload2d, scales)
+        name="codec_decode",
+    )(*operands)
 
 
 # ---------------------------------------------------------------------------
 # fused EF encode (quantize + pack + residual, single launch)
 # ---------------------------------------------------------------------------
 
-def _ef_encode_body(x_ref, scale_ref, payload_ref, e_ref, *, kind, bits,
-                    k, clip_abs):
+def _ef_encode_body(*refs, kind, bits, k, clip_abs, has_lut):
+    """The residual's dequant reads the SMEM table when there is one (it
+    pays the same per-element exp2 as decode otherwise)."""
+    if has_lut:
+        x_ref, scale_ref, lut_ref, payload_ref, e_ref = refs
+    else:
+        (x_ref, scale_ref, payload_ref, e_ref), lut_ref = refs, None
     x = x_ref[...]
     s = scale_ref[0]
     codes = _quant(x, s, None, kind=kind, k=k, clip_abs=clip_abs)
     payload_ref[...] = B.pack_lanes(codes, bits)
-    e_ref[...] = x - _dequant(codes, s, kind=kind, k=k)
-
-
-def _ef_encode_lut_body(x_ref, scale_ref, lut_ref, payload_ref, e_ref, *,
-                        kind, bits, k, clip_abs):
-    """EF encode whose residual dequant gathers from the SMEM table (the
-    residual pays the same per-element exp2 as decode otherwise)."""
-    x = x_ref[...]
-    s = scale_ref[0]
-    codes = _quant(x, s, None, kind=kind, k=k, clip_abs=clip_abs)
-    payload_ref[...] = B.pack_lanes(codes, bits)
-    e_ref[...] = x - _dequant(codes, s, kind=kind, k=k, lut=lut_ref[...])
+    e_ref[...] = x - _kdequant(codes, s, kind=kind, k=k, lut_ref=lut_ref)
 
 
 def ef_encode_pallas(x2d: jax.Array, scale: jax.Array, kind: str,
@@ -362,67 +341,23 @@ def ef_encode_pallas(x2d: jax.Array, scale: jax.Array, kind: str,
     er = enc_rows()
     li, lo = lanes_in(bits), lanes_out(bits)
     assert x2d.shape[1] == li and rows % er == 0
-    nb = rows // er
-    scale = jnp.asarray(scale, jnp.float32).reshape(1)
-    out_specs = [pl.BlockSpec((er, lo), lambda i: (i, 0)),
-                 pl.BlockSpec((er, li), lambda i: (i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((rows, lo), jnp.uint8),
-                 jax.ShapeDtypeStruct((rows, li), jnp.float32)]
+    operands = [x2d, jnp.asarray(scale, jnp.float32).reshape(1)]
+    in_specs = [pl.BlockSpec((er, li), lambda i: (i, 0)), smem()]
     if lut is not None:
-        return pl.pallas_call(
-            functools.partial(_ef_encode_lut_body, kind=kind, bits=bits,
-                              k=k, clip_abs=clip_abs),
-            grid=(nb,),
-            in_specs=[pl.BlockSpec((er, li), lambda i: (i, 0)),
-                      pl.BlockSpec((1,), lambda i: (0,)),
-                      _lut_spec()],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(x2d, scale, jnp.asarray(lut, jnp.float32))
+        operands.append(jnp.asarray(lut, jnp.float32))
+        in_specs.append(smem())
     return pl.pallas_call(
         functools.partial(_ef_encode_body, kind=kind, bits=bits, k=k,
-                          clip_abs=clip_abs),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((er, li), lambda i: (i, 0)),
-                  pl.BlockSpec((1,), lambda i: (0,))],
-        out_specs=out_specs,
-        out_shape=out_shape,
+                          clip_abs=clip_abs, has_lut=lut is not None),
+        grid=(rows // er,),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((er, lo), lambda i: (i, 0)),
+                   pl.BlockSpec((er, li), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((rows, lo), jnp.uint8),
+                   jax.ShapeDtypeStruct((rows, li), jnp.float32)],
         interpret=interpret,
-    )(x2d, scale)
-
-
-# ---------------------------------------------------------------------------
-# fused blockwise encode (sign + per-block scale + pack, single launch)
-# ---------------------------------------------------------------------------
-
-BLOCKWISE_ROWS = 8
-
-
-def _blockwise_encode_body(x_ref, payload_ref, scale_ref, *, bits):
-    codes, scale = grids.blockwise_quantize(x_ref[...])
-    payload_ref[...] = B.pack_lanes(codes, bits)
-    scale_ref[...] = scale
-
-
-def encode_blockwise_pallas(x2d: jax.Array, *, bits: int = 2,
-                            interpret: bool):
-    """(nb, block) f32 -> ((nb, block*bits/8) uint8 payload, (nb,)
-    scales) in one launch; nb must be a multiple of BLOCKWISE_ROWS."""
-    nb, block = x2d.shape
-    assert nb % BLOCKWISE_ROWS == 0
-    lo = block * bits // 8
-    grid = nb // BLOCKWISE_ROWS
-    return pl.pallas_call(
-        functools.partial(_blockwise_encode_body, bits=bits),
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((BLOCKWISE_ROWS, block), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((BLOCKWISE_ROWS, lo), lambda i: (i, 0)),
-                   pl.BlockSpec((BLOCKWISE_ROWS,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((nb, lo), jnp.uint8),
-                   jax.ShapeDtypeStruct((nb,), jnp.float32)],
-        interpret=interpret,
-    )(x2d)
+        name="codec_ef_encode",
+    )(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -476,22 +411,31 @@ def unpack_pallas(payload2d: jax.Array, bits: int, *, interpret: bool):
 # ---------------------------------------------------------------------------
 
 def _amax_kernel(x_ref, o_ref):
-    o_ref[0] = grids.block_amax(x_ref[...])
+    part = grids.block_amax(x_ref[...])
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        o_ref[0] = part
+
+    @pl.when(i > 0)
+    def _():
+        o_ref[0] = jnp.maximum(o_ref[0], part)
 
 
 def amax_pallas(x2d: jax.Array, *, interpret: bool) -> jax.Array:
-    """Per-block amax -> (grid,) partials. x2d: (R, 128), R % BLOCK_ROWS == 0."""
+    """Global amax, folded block by block into an SMEM scalar. x2d:
+    (R, 128), R % BLOCK_ROWS == 0."""
     rows = x2d.shape[0]
-    grid = rows // BLOCK_ROWS
-    partials = pl.pallas_call(
+    out = pl.pallas_call(
         _amax_kernel,
-        grid=(grid,),
+        grid=(rows // BLOCK_ROWS,),
         in_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((grid,), jnp.float32),
+        out_specs=smem(),
+        out_shape=jax.ShapeDtypeStruct((1,), jnp.float32),
         interpret=interpret,
     )(x2d)
-    return jnp.max(partials)
+    return out[0]
 
 
 def _log_quantize_kernel(x_ref, scale_ref, codes_ref, *, k_g: int):
@@ -507,7 +451,7 @@ def log_quantize_pallas(x2d: jax.Array, scale: jax.Array, k_g: int,
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            smem(),
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
@@ -530,7 +474,7 @@ def log_dequantize_pallas(codes2d: jax.Array, scale: jax.Array, k_g: int,
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            smem(),
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), out_dtype),
@@ -553,7 +497,7 @@ def uniform_quantize_pallas(x2d: jax.Array, scale: jax.Array, k_x: int,
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            smem(),
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES),
@@ -579,7 +523,7 @@ def uniform_dequantize_pallas(codes2d: jax.Array, scale: jax.Array, k_x: int,
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            smem(),
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), out_dtype),
@@ -602,7 +546,7 @@ def ternary_quantize_pallas(x2d: jax.Array, u2d: jax.Array,
     return pl.pallas_call(
         _ternary_quantize_kernel,
         grid=(grid,),
-        in_specs=[blk(), blk(), pl.BlockSpec((1,), lambda i: (0,))],
+        in_specs=[blk(), blk(), smem()],
         out_specs=blk(),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
         interpret=interpret,

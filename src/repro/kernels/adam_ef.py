@@ -9,8 +9,8 @@ Two Pallas passes over the parameter shard:
   pass B (`ef_quantize`): reads Delta+e, writes int8 codes and the new
       error-feedback residual e' = (Delta+e) - deq(codes).
 
-Scalars (alpha_t, beta, theta_t, eps) arrive as a (4,) f32 operand broadcast
-to every grid step (index_map pins block 0), which keeps them in SMEM on TPU.
+Scalars (alpha_t, beta, theta_t, eps) arrive as a (4,) f32 operand placed
+whole in SMEM; the amax is folded block by block into an SMEM scalar.
 
 Both kernel bodies call the canonical math in ``repro.opt.grids`` on their
 VMEM tiles, so the fused path is bit-identical to the jnp backend by
@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.quantize import BLOCK_ROWS, LANES
+from repro.comm.kernels import BLOCK_ROWS, LANES, smem
 from repro.opt import grids
 
 
@@ -36,7 +36,16 @@ def _moments_kernel(g_ref, m_ref, v_ref, e_ref, hp_ref,
     m_out[...] = m_new
     v_out[...] = v_new
     de_out[...] = de
-    amax_out[0] = grids.block_amax(de)
+    part = grids.block_amax(de)
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        amax_out[0] = part
+
+    @pl.when(i > 0)
+    def _():
+        amax_out[0] = jnp.maximum(amax_out[0], part)
 
 
 def adam_moments_pallas(g2d, m2d, v2d, e2d, hp, *, interpret: bool):
@@ -44,21 +53,21 @@ def adam_moments_pallas(g2d, m2d, v2d, e2d, hp, *, interpret: bool):
     rows = g2d.shape[0]
     grid = rows // BLOCK_ROWS
     blk = lambda: pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
-    m_new, v_new, de, partials = pl.pallas_call(
+    m_new, v_new, de, amax = pl.pallas_call(
         _moments_kernel,
         grid=(grid,),
-        in_specs=[blk(), blk(), blk(), blk(),
-                  pl.BlockSpec((4,), lambda i: (0,))],
-        out_specs=[blk(), blk(), blk(), pl.BlockSpec((1,), lambda i: (i,))],
+        in_specs=[blk(), blk(), blk(), blk(), smem()],
+        out_specs=[blk(), blk(), blk(), smem()],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((grid,), jnp.float32),
+            jax.ShapeDtypeStruct((1,), jnp.float32),
         ],
         interpret=interpret,
+        name="adam_ef_moments",
     )(g2d, m2d, v2d, e2d, hp)
-    return m_new, v_new, de, jnp.max(partials)
+    return m_new, v_new, de, amax[0]
 
 
 def _ef_quantize_kernel(de_ref, scale_ref, codes_ref, e_out, *, k_g: int):
@@ -74,11 +83,12 @@ def ef_quantize_pallas(de2d, scale, k_g: int, *, interpret: bool):
     return pl.pallas_call(
         functools.partial(_ef_quantize_kernel, k_g=k_g),
         grid=(grid,),
-        in_specs=[blk(), pl.BlockSpec((1,), lambda i: (0,))],
+        in_specs=[blk(), smem()],
         out_specs=[blk(), blk()],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="adam_ef_quantize",
     )(de2d, scale.reshape(1))

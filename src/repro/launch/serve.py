@@ -13,7 +13,39 @@ import argparse
 import time
 
 
-def main():
+def serve_params(model, args, *, log=print):
+    """Random weights from ``--seed``; with ``--quantized`` the fp32 tree
+    is replaced by code-resident leaves and dropped, so only the codes
+    stay on the device."""
+    import jax
+    from repro.serve import params_nbytes, quantize_params
+    params = model.init(jax.random.PRNGKey(args.seed))
+    fp_bytes = params_nbytes(params)
+    if not args.quantized:
+        log(f"arch={args.arch} params={fp_bytes / 1e6:.1f}MB fp32")
+        return params
+    params = quantize_params(params, k_x=args.k_x, pack=not args.no_pack)
+    q_bytes = params_nbytes(params)
+    log(f"arch={args.arch} params={fp_bytes / 1e6:.1f}MB fp32 -> "
+        f"{q_bytes / 1e6:.1f}MB resident codes "
+        f"({q_bytes / fp_bytes:.2f}x, measured)")
+    return params
+
+
+def make_session(model, params, args):
+    """The ``ServeSession`` the command line asks for (the launcher's
+    main path; ``chip_smoke.py`` runs it too)."""
+    from repro.serve import ServeSession
+    return ServeSession(model, params, slots=args.slots,
+                        max_seq=args.max_seq, seed=args.seed,
+                        aot_dir=args.aot_dir,
+                        fused_matmul=not args.no_fused_matmul,
+                        paged=args.paged, page_size=args.page_size,
+                        num_pages=args.num_pages,
+                        prefill_chunk=args.prefill_chunk)
+
+
+def build_parser():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -48,50 +80,31 @@ def main():
                     help="tag requests round-robin interactive/standard/"
                          "batch to exercise priority admission+preemption")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache dir (default "
-                         "$REPRO_COMPILE_CACHE or ~/.cache/repro/xla)")
-    ap.add_argument("--no-compile-cache", action="store_true")
+    ap.add_argument("--no-compile-cache", action="store_true",
+                    help="run without the persistent XLA compilation "
+                         "cache (see repro.perf.cache for where it lives)")
     ap.add_argument("--aot-dir", default=None, metavar="DIR",
                     help="AOT artifact dir for the compiled decode step "
                          "(repro.perf.aot): warm restarts skip compilation")
-    args = ap.parse_args()
+    return ap
 
-    import jax
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+
     from repro import perf
     if not args.no_compile_cache:
-        cache_dir = perf.enable_persistent_cache(args.compile_cache)
-        if cache_dir:
-            print(f"compile cache: {cache_dir}")
+        print(f"compile cache: {perf.enable_persistent_cache()}")
     import numpy as np
     from repro.configs import get_config
     from repro.models.model import Model
-    from repro.serve import (Request, ServeSession, params_nbytes,
-                             quantize_params)
+    from repro.serve import Request
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.arch_type == "encdec" or cfg.input_mode != "tokens":
         raise SystemExit("serve CLI demo supports token-input decoder LMs")
     model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
-    fp_bytes = params_nbytes(params)
-    if args.quantized:
-        params = quantize_params(params, k_x=args.k_x,
-                                 pack=not args.no_pack)
-        q_bytes = params_nbytes(params)
-        print(f"arch={args.arch} params={fp_bytes / 1e6:.1f}MB fp32 -> "
-              f"{q_bytes / 1e6:.1f}MB resident codes "
-              f"({q_bytes / fp_bytes:.2f}x, measured)")
-    else:
-        print(f"arch={args.arch} params={fp_bytes / 1e6:.1f}MB fp32")
-
-    session = ServeSession(model, params, slots=args.slots,
-                           max_seq=args.max_seq, seed=args.seed,
-                           aot_dir=args.aot_dir,
-                           fused_matmul=not args.no_fused_matmul,
-                           paged=args.paged, page_size=args.page_size,
-                           num_pages=args.num_pages,
-                           prefill_chunk=args.prefill_chunk)
+    session = make_session(model, serve_params(model, args), args)
     if args.paged:
         print(f"paged cache: {session.num_pages} pages x "
               f"{session.page_size} tokens "

@@ -39,18 +39,11 @@ def _run_adaptive(args, model, mesh, tc):
     import jax
     import math
     from repro.adapt.controller import AdaptConfig, AdaptiveController
-    from repro.configs import get_config
     from repro.data.pipeline import batch_for_model
-    from repro.train.session import SessionConfig
 
-    cfg = get_config(args.arch, smoke=args.smoke)
-    batches = batch_for_model(cfg, args.seq, args.global_batch,
+    batches = batch_for_model(model.cfg, args.seq, args.global_batch,
                               seed=args.seed)
-    sc = SessionConfig(log_every=args.log_every,
-                       ckpt_every=args.ckpt_every, ckpt_dir=args.ckpt_dir,
-                       ckpt_keep=args.ckpt_keep, ckpt_codec=args.ckpt_codec,
-                       scan_chunk=args.scan_chunk, prefetch=args.prefetch,
-                       aot_dir=args.aot_dir)
+    sc = session_config(args)
     acfg = AdaptConfig(budget_ratio=args.adapt_budget,
                        replan_every=args.replan_every,
                        ema_decay=args.adapt_ema)
@@ -116,7 +109,53 @@ def _plan_summary(plan):
     return " ".join(f"{s}x{n}" for s, n in sorted(counts.items()))
 
 
-def main():
+def session_config(args):
+    """The ``TrainSession`` settings the command line asks for."""
+    from repro.train.session import SessionConfig
+    return SessionConfig(log_every=args.log_every,
+                         ckpt_every=args.ckpt_every, ckpt_dir=args.ckpt_dir,
+                         ckpt_keep=args.ckpt_keep,
+                         ckpt_codec=args.ckpt_codec,
+                         scan_chunk=args.scan_chunk,
+                         prefetch=args.prefetch, aot_dir=args.aot_dir)
+
+
+def train_config(args, topo):
+    """The ``TrainConfig`` the command line asks for."""
+    from repro.dist.step import TrainConfig
+    return TrainConfig(
+        alpha=args.alpha, beta=args.beta, theta=args.theta,
+        schedule=args.schedule,
+        grad_k=args.grad_bits or None,
+        weight_k=args.weight_bits or None,
+        weight_absolute=args.weight_absolute,
+        model_gather_quant=args.model_gather_quant or None,
+        error_feedback=not args.no_ef,
+        worker_axes=("pod", "data"),
+        topology=topo,
+        mode="adaptive" if args.adaptive else args.mode)
+
+
+def make_session(model, mesh, tc, args, *, log=print):
+    """Build the train step, its wire accounting and the session that
+    drives it over the synthetic batch stream: the launcher's main path
+    (``chip_smoke.py`` runs it too). Returns ``(art, comm, session)``."""
+    import jax
+    from repro.data.pipeline import batch_for_model
+    from repro.dist.step import make_train_step
+    from repro.train.loop import comm_bytes_per_step
+    from repro.train.session import TrainSession
+    art = make_train_step(model, mesh, tc)
+    comm = comm_bytes_per_step(art, tc)
+    batches = batch_for_model(model.cfg, args.seq, args.global_batch,
+                              seed=args.seed)
+    sess = TrainSession.from_artifacts(art, batches, session_config(args),
+                                       key=jax.random.PRNGKey(args.seed),
+                                       log=log)
+    return art, comm, sess
+
+
+def build_parser():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -190,15 +229,19 @@ def main():
                     help="restore the newest checkpoint under --ckpt-dir")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--history-out", default=None)
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache dir (default "
-                         "$REPRO_COMPILE_CACHE or ~/.cache/repro/xla)")
-    ap.add_argument("--no-compile-cache", action="store_true")
+    ap.add_argument("--no-compile-cache", action="store_true",
+                    help="run without the persistent XLA compilation "
+                         "cache (see repro.perf.cache for where it lives)")
     ap.add_argument("--aot-dir", default=None, metavar="DIR",
                     help="AOT step-artifact dir: restart/resume loads the "
                          "serialized compiled train step instead of "
                          "tracing+compiling (repro.perf.aot)")
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None):
+    ap = build_parser()
+    args = ap.parse_args(argv)
     if args.resume and not args.ckpt_dir:
         ap.error("--resume requires --ckpt-dir")
     args.adaptive = args.adaptive or args.mode == "adaptive"
@@ -214,15 +257,10 @@ def main():
     import jax
     from repro import perf
     if not args.no_compile_cache:
-        cache_dir = perf.enable_persistent_cache(args.compile_cache)
-        if cache_dir:
-            print(f"compile cache: {cache_dir}")
+        print(f"compile cache: {perf.enable_persistent_cache()}")
     from repro.configs import get_config
     from repro.models.model import Model
     from repro.launch.mesh import make_local_mesh
-    from repro.dist.step import make_train_step, TrainConfig
-    from repro.train.loop import comm_bytes_per_step
-    from repro.train.session import SessionConfig, TrainSession
     from repro.data.pipeline import batch_for_model
 
     from repro.dist import topology as T
@@ -240,17 +278,7 @@ def main():
     cfg = get_config(args.arch, smoke=args.smoke)
     model = Model(cfg)
     mesh = make_local_mesh(data=args.data, model=args.model, pod=args.pod)
-    tc = TrainConfig(
-        alpha=args.alpha, beta=args.beta, theta=args.theta,
-        schedule=args.schedule,
-        grad_k=args.grad_bits or None,
-        weight_k=args.weight_bits or None,
-        weight_absolute=args.weight_absolute,
-        model_gather_quant=args.model_gather_quant or None,
-        error_feedback=not args.no_ef,
-        worker_axes=("pod", "data"),
-        topology=topo,
-        mode="adaptive" if args.adaptive else args.mode)
+    tc = train_config(args, topo)
     if args.tune_buckets:
         from repro.perf.autotune import tune_exchange_buckets
         # probe batch from a fresh same-seed generator: the training
@@ -265,8 +293,7 @@ def main():
     if args.adaptive:
         _run_adaptive(args, model, mesh, tc)
         return
-    art = make_train_step(model, mesh, tc)
-    comm = comm_bytes_per_step(art, tc)
+    art, comm, sess = make_session(model, mesh, tc, args)
     print(f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"workers={art.n_workers}")
     print(f"comm/device/step: exchange={comm['update_exchange_bytes']/1e6:.2f}MB "
@@ -274,16 +301,6 @@ def main():
     if comm["tiers"]["intra"]["total"]:
         print(f"  per tier: inter={comm['tiers']['inter']['total']/1e6:.2f}MB "
               f"intra={comm['tiers']['intra']['total']/1e6:.2f}MB")
-
-    batches = batch_for_model(cfg, args.seq, args.global_batch,
-                              seed=args.seed)
-    sc = SessionConfig(log_every=args.log_every, ckpt_every=args.ckpt_every,
-                       ckpt_dir=args.ckpt_dir, ckpt_keep=args.ckpt_keep,
-                       ckpt_codec=args.ckpt_codec,
-                       scan_chunk=args.scan_chunk, prefetch=args.prefetch,
-                       aot_dir=args.aot_dir)
-    sess = TrainSession.from_artifacts(art, batches, sc,
-                                       key=jax.random.PRNGKey(args.seed))
     try:
         start = sess.resume(args.ckpt_dir) if args.resume else 0
         if start:

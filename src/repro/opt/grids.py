@@ -58,7 +58,10 @@ def log_quantize(x: jax.Array, scale: jax.Array, k_g: int) -> jax.Array:
     e_near = jnp.where(y >= mid, e_lo, e_lo + 1.0)
     e_near = jnp.clip(e_near, 0.0, float(k_g))
     # zero threshold: halfway to the smallest level
-    is_zero = (y < jnp.exp2(-float(k_g)) * 0.5) | (x == 0.0)
+    # the threshold is a host constant (2^-k_g is exact in f32): a
+    # constant exp2 would be traced into the kernels, which TPU kernels
+    # cannot lower
+    is_zero = (y < 2.0 ** -k_g * 0.5) | (x == 0.0)
     mag = jnp.where(is_zero, 0.0, float(k_g) + 1.0 - e_near)
     return jnp.where(x < 0, -mag, mag).astype(jnp.int8)
 
@@ -126,27 +129,6 @@ def uniform_quantize(x: jax.Array, scale: jax.Array, k_x: int) -> jax.Array:
 def uniform_dequantize(codes: jax.Array, scale: jax.Array, k_x: int) -> jax.Array:
     n = float(2 ** k_x)
     return codes.astype(jnp.float32) / n * scale
-
-
-@functools.lru_cache(maxsize=None)
-def uniform_dequant_table(k_x: int, bits: int) -> np.ndarray:
-    """Scale-1 uniform dequant values per ``bits``-wide lane code, ordered
-    by raw lane value (index = code + 2^{bits-1}) - the uniform-grid twin
-    of :func:`log_dequant_table`, built by evaluating the oracle itself.
-    ``codes / 2^k`` is an exact power-of-two division, so the gathered
-    value times ``scale`` rounds identically to the elementwise form.
-    """
-    n = 1 << bits
-    with jax.ensure_compile_time_eval():
-        codes = jnp.arange(-(n // 2), n // 2, dtype=jnp.int32)
-        table = uniform_dequantize(codes, jnp.float32(1.0), k_x)
-    return np.asarray(table)
-
-
-# the gather is grid-agnostic: it applies any scale-1 lane table and
-# multiplies by scale. Alias it under a neutral name for uniform-grid
-# callers (repro.comm.matmul).
-dequantize_lut = log_dequantize_lut
 
 
 # ---------------------------------------------------------------------------

@@ -8,7 +8,7 @@ restarts:
   * :mod:`repro.perf.profiling` - ``jax.profiler`` trace harness with
     per-bench annotations (``benchmarks/run.py --trace``);
   * :mod:`repro.perf.cache`     - persistent XLA compilation cache
-    setup shared by the launchers and sessions;
+    placement shared by the launchers;
   * :mod:`repro.perf.aot`       - ahead-of-time export/load of compiled
     train/decode steps keyed on (config digest, mesh, mode, codec);
   * :mod:`repro.perf.autotune`  - per-backend tile-width tuning for the
@@ -18,14 +18,12 @@ restarts:
 from repro.perf import aot, autotune, cache, profiling
 from repro.perf.aot import load_or_compile, step_key
 from repro.perf.cache import (cache_entries, disable_persistent_cache,
-                              enable_persistent_cache,
-                              ensure_persistent_cache)
+                              enable_persistent_cache)
 from repro.perf.profiling import annotate, trace
 
 __all__ = [
     "aot", "autotune", "cache", "profiling",
     "annotate", "trace",
     "cache_entries", "disable_persistent_cache", "enable_persistent_cache",
-    "ensure_persistent_cache",
     "load_or_compile", "step_key",
 ]

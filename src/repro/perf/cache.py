@@ -1,57 +1,42 @@
-"""Persistent XLA compilation cache setup.
+"""Persistent XLA compilation cache placement.
 
-One switch for the whole repo: the launchers enable it by default
-(opt out with ``--no-compile-cache``), the sessions enable it when the
-``REPRO_COMPILE_CACHE`` env var names a directory (a library must not
-silently redirect global jax config, so env-less session construction
-leaves the config alone). Entries are content-addressed by XLA on the
-(HLO, compile options, backend) fingerprint, so a restarted fleet
-recompiles nothing that already compiled anywhere sharing the
-directory.
+Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX keeps its cache in that
+directory by itself and nothing here sets a directory. Otherwise the
+launchers (``repro.launch.{train,serve}``, ``chip_smoke.py``) put it at
+:data:`DEFAULT_CACHE_DIR`, one fixed, gitignored directory inside the
+checkout: the path is part of what the cache is keyed on, so a directory
+that moves never hits. Libraries (the sessions) never touch the setting.
 
-Env knobs::
-
-  REPRO_COMPILE_CACHE=<dir>   enable and place the cache (sessions too)
-  REPRO_COMPILE_CACHE=0|off   force-disable, even in launchers
-
-The jax config knobs this sets: ``jax_compilation_cache_dir``,
-``jax_persistent_cache_min_entry_size_bytes``,
-``jax_persistent_cache_min_compile_time_secs`` (both minimums default
-to 0 here: the codec kernels are small and fast to compile, exactly the
-entries the stock 1-second threshold would skip).
+Entries are content-addressed by XLA on the (HLO, compile options,
+backend) fingerprint, so a restart recompiles nothing that already
+compiled anywhere sharing the directory. Both the entry-size and the
+compile-time minimums are set to 0: the codec kernels are small and fast
+to compile, exactly the entries the stock 1-second threshold would skip.
 """
 from __future__ import annotations
 
 import os
-from typing import Optional
 
 import jax
 
-ENV_VAR = "REPRO_COMPILE_CACHE"
-DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "repro", "xla")
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/.jax_cache (this file is <checkout>/src/repro/perf/cache.py)
+DEFAULT_CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))))),
+    ".jax_cache")
 
-_OFF = ("0", "off", "false", "no")
 
-
-def enable_persistent_cache(cache_dir: Optional[str] = None, *,
-                            min_entry_size_bytes: int = 0,
-                            min_compile_time_secs: float = 0.0,
-                            ) -> Optional[str]:
-    """Point jax's persistent compilation cache at ``cache_dir``.
-
-    Resolution order: explicit ``cache_dir`` > ``$REPRO_COMPILE_CACHE``
-    > :data:`DEFAULT_CACHE_DIR`; an env value of ``0``/``off`` disables
-    and returns None. Returns the directory in use.
-    """
-    env = os.environ.get(ENV_VAR, "").strip()
-    if cache_dir is None:
-        if env.lower() in _OFF:
-            return None
-        cache_dir = env or DEFAULT_CACHE_DIR
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+def enable_persistent_cache(*, min_entry_size_bytes: int = 0,
+                            min_compile_time_secs: float = 0.0) -> str:
+    """Turn jax's persistent compilation cache on and return the
+    directory it writes to: ``$JAX_COMPILATION_CACHE_DIR`` when set (left
+    to jax), else :data:`DEFAULT_CACHE_DIR`."""
+    cache_dir = os.environ.get(ENV_VAR, "").strip()
+    if not cache_dir:
+        cache_dir = DEFAULT_CACHE_DIR
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_enable_compilation_cache", True)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                       min_entry_size_bytes)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
@@ -60,32 +45,21 @@ def enable_persistent_cache(cache_dir: Optional[str] = None, *,
     return cache_dir
 
 
-def ensure_persistent_cache() -> Optional[str]:
-    """Session-side hook: enable the cache iff ``$REPRO_COMPILE_CACHE``
-    opts in (a library must not silently repoint global jax config)."""
-    env = os.environ.get(ENV_VAR, "").strip()
-    if not env or env.lower() in _OFF:
-        return None
-    if jax.config.jax_compilation_cache_dir:
-        return jax.config.jax_compilation_cache_dir  # already configured
-    return enable_persistent_cache(env)
+def disable_persistent_cache() -> None:
+    jax.config.update("jax_enable_compilation_cache", False)
+    _reset_cache_state()
 
 
 def _reset_cache_state() -> None:
     """jax initializes its cache object once, at the first compile; a
-    dir configured after that point is silently ignored. Resetting the
+    setting changed after that point is silently ignored. Resetting the
     cached state makes enable/disable effective mid-process (e.g. a
-    session constructed after model init already compiled something)."""
+    launcher that already compiled something)."""
     try:
         from jax.experimental.compilation_cache import compilation_cache
         compilation_cache.reset_cache()
     except Exception:
         pass  # private-ish API: a jax without it just loses mid-process
-
-
-def disable_persistent_cache() -> None:
-    jax.config.update("jax_compilation_cache_dir", None)
-    _reset_cache_state()
 
 
 def cache_entries(cache_dir: str) -> int:

@@ -87,6 +87,7 @@ def _gather_pallas(pool, ptab, *, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, npag, ps, K, hd), pool.dtype),
         interpret=interpret,
+        name="paged_gather",
     )(ptab, pool)
     return out.reshape(B, npag * ps, K, hd)
 

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import comm
+from repro.comm import bits as B
 from repro.opt import engine, grids
 
 _STACKED_KEYS = ("blocks", "enc_blocks")
@@ -164,11 +165,12 @@ def _path_head(path) -> Optional[str]:
 def _quantize_leaf(p: jax.Array, k_x: int, absolute: bool, per_layer: bool,
                    pack: bool) -> QuantizedLeaf:
     x = p.astype(jnp.float32)
-    # engine dispatch: fused Pallas amax+quantize tiles on TPU; vmapped
-    # over the layer dim for stacked leaves (one scale per layer)
+    # engine dispatch: fused Pallas amax+quantize tiles on TPU; mapped
+    # over the layer dim for stacked leaves (one scale per layer). A
+    # loop, not vmap: the kernels' SMEM scalars cannot take a batch dim
     if per_layer:
-        codes, scale = jax.vmap(
-            lambda xl: engine.quantize_uniform(xl, k_x, absolute=absolute))(x)
+        codes, scale = jax.lax.map(
+            lambda xl: engine.quantize_uniform(xl, k_x, absolute=absolute), x)
     else:
         codes, scale = engine.quantize_uniform(x, k_x, absolute=absolute)
     # the registry's exact (unclipped) lane for this grid: 3/4/6-bit
@@ -178,8 +180,14 @@ def _quantize_leaf(p: jax.Array, k_x: int, absolute: bool, per_layer: bool,
     if pack and codec.bits < 8:
         pack_bits = codec.bits
         lead = codes.shape[:-1]
-        rows = comm.pack_rows(codes.reshape((-1, codes.shape[-1])),
-                              pack_bits)
+        flat = codes.reshape((-1, codes.shape[-1]))
+        # rows of at least one packing block are padded to whole blocks,
+        # which the fused matmul's column tiles need (the unpack paths
+        # cut the codes back to the logical width)
+        blk = B.block_codes(pack_bits)
+        if flat.shape[1] >= blk and flat.shape[1] % blk:
+            flat = jnp.pad(flat, ((0, 0), (0, -flat.shape[1] % blk)))
+        rows = comm.pack_rows(flat, pack_bits)
         codes = rows.reshape(lead + (rows.shape[-1],))
     return QuantizedLeaf(codes=codes, scale=scale, k_x=k_x,
                          shape=tuple(p.shape), dtype=jnp.dtype(p.dtype).name,

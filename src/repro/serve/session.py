@@ -59,7 +59,6 @@ import numpy as np
 
 from repro.models.layers import ShardCtx
 from repro.perf import aot
-from repro.perf import cache as perf_cache
 from repro.serve.paged import PagePool
 from repro.serve.quantized import is_quantized, make_dequant_gather
 
@@ -211,7 +210,6 @@ class ServeSession:
         self._chunk_fns: Dict[bool, Callable] = {}   # is_last -> jitted
         self._aot_dir = aot_dir if self._local else None
         self._step_ready: Dict[bool, Callable] = {}  # sample -> executable
-        perf_cache.ensure_persistent_cache()  # opt-in via env, see cache.py
         self._state = self._init_state()
         self._base_key = _raw_key(base_key if base_key is not None
                                   else jax.random.PRNGKey(seed))
@@ -792,6 +790,16 @@ class ServeSession:
                                      stats=self.stats)
             self._step_ready[sample] = fn
         return fn
+
+    def compiled_step(self, sample: bool = False):
+        """The decode-step executable (greedy or sampling variant) at the
+        session's parameter and state shapes, for inspecting its memory
+        use and kernels; after a dispatch of that variant it is found
+        already compiled in this process."""
+        fn = self._step_callable(sample)
+        if hasattr(fn, "as_text"):
+            return fn
+        return fn.lower(self.params, self._state).compile()
 
     def step(self):
         """One decode step for every slot (a single device dispatch),

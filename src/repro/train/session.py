@@ -51,7 +51,6 @@ import numpy as np
 
 from repro.checkpoint import store
 from repro.perf import aot
-from repro.perf import cache as perf_cache
 
 
 @dataclasses.dataclass
@@ -78,8 +77,8 @@ class SessionConfig:
     # AOT step artifacts (repro.perf.aot): serialized compiled train
     # steps keyed on (config digest, mesh, mode, codec, arg signature).
     # A warm dir skips trace+lower+compile entirely on restart; None
-    # keeps plain jit (which still hits the persistent XLA cache when
-    # REPRO_COMPILE_CACHE is set).
+    # keeps plain jit (which still hits the persistent XLA cache where
+    # one is enabled, see repro.perf.cache).
     aot_dir: Optional[str] = None
 
 
@@ -310,6 +309,13 @@ class _Prefetcher:
 # the session
 # ---------------------------------------------------------------------------
 
+def _abstract(x):
+    """Shape/dtype/sharding of an array argument (other leaves as-is)."""
+    if isinstance(x, jax.Array):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+    return x
+
+
 class TrainSession:
     """Async training session over one program (dist or single-machine).
 
@@ -384,13 +390,11 @@ class TrainSession:
         # means a zero-compilation session)
         self.stats = {"dispatches": 0, "syncs": 0, "steps": 0, "ckpts": 0,
                       "compilations": 0, "aot_loads": 0}
-        # opt-in persistent XLA cache (no-op unless REPRO_COMPILE_CACHE
-        # is set; the launchers enable it unconditionally)
-        perf_cache.ensure_persistent_cache()
         self._ckpt_q: Optional[queue.Queue] = None
         self._ckpt_thread: Optional[threading.Thread] = None
         self._ckpt_err: Optional[BaseException] = None
         self._closed = False
+        self._last_dispatch = None
 
     # -- constructors ---------------------------------------------------
 
@@ -677,11 +681,14 @@ class TrainSession:
             sl, i0 = self._slot, self._step
             if self._sring is None:
                 args = (self._state, self._ring, sl, batch)
-                self._state, self._ring = self._built_step(k, args)(*args)
             else:
                 args = (self._state, self._ring, self._sring, sl, batch)
-                self._state, self._ring, self._sring = \
-                    self._built_step(k, args)(*args)
+            self._last_dispatch = (k, jax.tree.map(_abstract, args))
+            out = self._built_step(k, args)(*args)
+            if self._sring is None:
+                self._state, self._ring = out
+            else:
+                self._state, self._ring, self._sring = out
             self._record_segment(i0 + 1, sl, k)
             self._slot += k
             self._step += k
@@ -722,6 +729,17 @@ class TrainSession:
     def state(self):
         """The live train-state pytree (valid between dispatches)."""
         return self._state
+
+    def compiled_step(self):
+        """The executable of the most recent ``run`` dispatch, for
+        inspecting its memory use and kernels. Lowering at the recorded
+        argument shapes finds the program already compiled in this
+        process, so nothing is compiled again."""
+        if self._last_dispatch is None:
+            raise RuntimeError("no step has been dispatched yet")
+        k, sig = self._last_dispatch
+        fn = self._built_step(k, sig)
+        return fn if hasattr(fn, "as_text") else fn.lower(*sig).compile()
 
     @property
     def step(self) -> int:

@@ -60,6 +60,18 @@ class TestBitwiseParity:
             x, backend=backend))(x)
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
 
+    @pytest.mark.parametrize("backend", BACKENDS)
+    def test_rows_padded_to_whole_blocks(self, backend):
+        # a packed row of at least one block is padded to whole blocks
+        # (600 -> 1024 6-bit codes); the output is cut back to n
+        w, leaf = _leaf(4, (24, 600))
+        assert leaf.codes.shape == (24, 2 * 384)
+        assert leaf.dequantize().shape == w.shape
+        x = jax.random.normal(jax.random.PRNGKey(11), (3, 24), jnp.float32)
+        ref = x @ leaf.dequantize().astype(x.dtype)
+        got = leaf.astype(x.dtype).matmul(x, backend=backend)
+        np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+
     def test_reflection_dispatch(self):
         # models write ``x @ w.astype(x.dtype)``; jax arrays defer to the
         # leaf's __rmatmul__, so that exact spelling hits the fused path
@@ -89,6 +101,19 @@ class TestBitwiseParity:
         for backend in BACKENDS:
             got = leaf.astype(x.dtype).matmul(x, backend=backend)
             np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+
+
+class TestKTiles:
+    def test_long_k_accumulates_in_f32(self):
+        # K past one K tile: the kernel sums K tiles in an f32 scratch,
+        # which reorders the reduction - equal up to f32 rounding
+        k = 2 * MM._MAX_K_TILE
+        _, leaf = _leaf(4, (k, 512))      # 6-bit lanes, one whole block
+        x = jax.random.normal(jax.random.PRNGKey(10), (4, k), jnp.float32)
+        ref = x @ leaf.dequantize().astype(x.dtype)
+        got = leaf.astype(x.dtype).matmul(x, backend="pallas")
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
 
 
 class TestTake:

@@ -157,36 +157,43 @@ class TestSessionAot:
 
 
 class TestPersistentCache:
-    def test_enable_after_first_compile_takes_effect(self, tmp_path):
-        prev = jax.config.jax_compilation_cache_dir
+    @pytest.fixture
+    def restore_cache_config(self):
+        keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache")
+        prev = {k: getattr(jax.config, k) for k in keys}
+        yield
+        for k, v in prev.items():
+            jax.config.update(k, v)
+        perf.cache._reset_cache_state()
+
+    def test_enable_after_first_compile_takes_effect(
+            self, tmp_path, monkeypatch, restore_cache_config):
         d = str(tmp_path / "xla")
-        try:
-            # a compile BEFORE enabling initializes jax's cache state
-            jax.jit(lambda x: x - 3)(jnp.ones(4)).block_until_ready()
-            assert perf.enable_persistent_cache(d) == d
-            jax.jit(lambda x: x * 3 + 7)(jnp.ones(16)).block_until_ready()
-            assert perf.cache_entries(d) >= 1
-        finally:
-            if prev:
-                perf.enable_persistent_cache(prev)
-            else:
-                perf.disable_persistent_cache()
-
-    def test_env_off_disables(self, monkeypatch):
-        monkeypatch.setenv(perf.cache.ENV_VAR, "off")
-        assert perf.enable_persistent_cache() is None
-        assert perf.ensure_persistent_cache() is None
-
-    def test_ensure_requires_opt_in(self, monkeypatch):
         monkeypatch.delenv(perf.cache.ENV_VAR, raising=False)
-        prev = jax.config.jax_compilation_cache_dir
-        try:
-            perf.disable_persistent_cache()
-            assert perf.ensure_persistent_cache() is None
-            assert jax.config.jax_compilation_cache_dir is None
-        finally:
-            if prev:
-                perf.enable_persistent_cache(prev)
+        monkeypatch.setattr(perf.cache, "DEFAULT_CACHE_DIR", d)
+        # a compile BEFORE enabling initializes jax's cache state
+        jax.jit(lambda x: x - 3)(jnp.ones(4)).block_until_ready()
+        assert perf.enable_persistent_cache() == d
+        jax.jit(lambda x: x * 3 + 7)(jnp.ones(16)).block_until_ready()
+        assert perf.cache_entries(d) >= 1
+
+    def test_env_set_leaves_the_directory_to_jax(
+            self, tmp_path, monkeypatch, restore_cache_config):
+        d = str(tmp_path / "from_env")
+        monkeypatch.setenv(perf.cache.ENV_VAR, d)
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert perf.enable_persistent_cache() == d
+        # the code sets no directory: jax reads the variable itself
+        assert jax.config.jax_compilation_cache_dir is None
+
+    def test_env_unset_uses_the_fixed_checkout_path(
+            self, monkeypatch, restore_cache_config):
+        monkeypatch.delenv(perf.cache.ENV_VAR, raising=False)
+        d = perf.enable_persistent_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert d == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == d
+        assert os.path.isdir(d)
 
     def test_cache_entries_ignores_sidecars(self, tmp_path):
         (tmp_path / "entry").write_bytes(b"x")
