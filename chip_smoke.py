@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import faulthandler
 import functools
 import gc
 import json
@@ -328,9 +329,10 @@ def four_worker_phase(cfg, *, workers=4, seq=TRAIN_SEQ, steps=3,
     state = art.init_state(jax.random.PRNGKey(0))
     step = jax.jit(art.step_fn, donate_argnums=(0,))
     dist_losses = []
-    for _ in range(steps):
+    for i in range(steps):
         state, metrics = step(state, batch)
         dist_losses.append(float(metrics["loss"]))
+        print(f"  {workers}-worker step {i + 1} loss {dist_losses[-1]:.4f}")
     peaks = [peak_bytes(d) for d in jax.devices()]
     master = [np.asarray(x) for x in jax.tree.leaves(state["master"])]
     del state, step
@@ -355,9 +357,10 @@ def four_worker_phase(cfg, *, workers=4, seq=TRAIN_SEQ, steps=3,
     params = model.init(jax.random.PRNGKey(0))
     ostate = opt.init(params)
     ref_losses = []
-    for _ in range(steps):
+    for i in range(steps):
         params, ostate, loss = ref_step(params, ostate)
         ref_losses.append(float(loss))
+        print(f"  Algorithm 1 step {i + 1} loss {ref_losses[-1]:.4f}")
 
     flips = total = 0
     worst = 0.0
@@ -391,6 +394,10 @@ def main(argv=None):
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the four-worker equivalence phase")
     args = ap.parse_args(argv)
+    # lines reach a pipe as they are printed, and a crash in native code
+    # still leaves the Python stack on stderr
+    sys.stdout.reconfigure(line_buffering=True)
+    faulthandler.enable()
     _import_repro()
     import jax
 
