@@ -369,10 +369,12 @@ def bench_train(emit, steps=24, chunk=8):
 
 
 def bench_startup(emit, steps=2):
-    """Cold vs warm startup through repro.perf: a fresh persistent XLA
-    cache + AOT step-artifact dir, then a TrainSession and a
-    ServeSession built TWICE against them. Cold pays trace + lower +
-    compile (+ export); warm deserializes the compiled step. Rows are
+    """Cold vs warm startup through repro.perf's AOT step artifacts: a
+    fresh AOT dir, then a TrainSession and a ServeSession built TWICE
+    against it. Cold pays trace + lower + compile (+ export); warm
+    deserializes the compiled step. The persistent XLA cache is off for
+    the whole bench, so nothing a previous run left there can make the
+    cold sessions warm; it is restored afterwards. Rows are
     setup-through-first-work wall time; the speedup rows are the
     machine-independent signal.
 
@@ -400,7 +402,8 @@ def bench_startup(emit, steps=2):
     params = model.init(jax.random.PRNGKey(0))
 
     tmp = tempfile.mkdtemp(prefix="bench_startup_")
-    cache_dir = perf.enable_persistent_cache()
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    perf.disable_persistent_cache()
     try:
         def train_once():
             t0 = time.perf_counter()
@@ -440,8 +443,6 @@ def bench_startup(emit, steps=2):
              f"{sst_w['aot_loads']}aot_loads")
         emit("startup_serve_speedup", 0.0, f"{s_cold / s_warm:.2f}x_warm",
              s_cold / s_warm)
-        emit("startup_compile_cache_entries", 0.0,
-             f"{perf.cache_entries(cache_dir)}entries")
 
         if os.environ.get("BENCH_ASSERT_STARTUP"):
             assert warm < cold, (
@@ -453,6 +454,8 @@ def bench_startup(emit, steps=2):
             assert sst_w["compilations"] == 0 and sst_w["aot_loads"] >= 1, (
                 f"warm ServeSession recompiled: {sst_w}")
     finally:
+        if cache_was_on:
+            perf.enable_persistent_cache()
         shutil.rmtree(tmp, ignore_errors=True)
 
 

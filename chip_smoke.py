@@ -23,9 +23,7 @@ published (d_model 4096, 32 heads / 4 KV heads x 128, d_ff 11008):
 * four chips: 1 layer and the train phase's vocabulary slice, one
   2048-token sequence per worker, the same on every worker. Each worker
   keeps m, v and e for the whole model (Algorithm 3), and Algorithm 1
-  needs all of it on the first chip; one layer keeps both within 16 GB
-  and keeps the four-device compile (the largest cost of that call)
-  short.
+  needs all of it on the first chip; one layer keeps both within 16 GB.
 """
 from __future__ import annotations
 
@@ -61,16 +59,21 @@ FIRST_LOSS_TOL = 0.02
 # four workers with identical batches vs Algorithm 1 on one chip. Losses:
 # step 1 sees identical weights, so only reduction order differs (the
 # mean of four identical rows is exact, the loss psum is not ordered
-# like one device's sum): relative 1e-3. Masters: differently fused
-# programs give gradients that differ in the last bits, which can flip a
-# log-grid code that sits on a boundary; one flip moves that element by
-# at most one grid step, at most the step's amax scale of
-# alpha * m / sqrt(v + eps) <= alpha / sqrt(1 - theta) ~ 32 * alpha, and
-# error feedback hands the difference on instead of letting it grow. So
-# masters agree within 32 * alpha per step taken, and almost everywhere
-# exactly: at most 1e-3 of the elements may differ by more than 1e-6.
+# like one device's sum): relative 1e-3. Masters: the two programs fuse
+# the same bf16 forward/backward differently, so their gradients differ
+# by bf16 roundings (2^-8 relative). That moves each leaf's exchange
+# scale, amax |alpha * m / sqrt(v) + e|, by a few such roundings, and
+# with it the update of EVERY element of the leaf (same code, scale off
+# by ~2^-8..2^-6 relative); it can also flip the code of an element
+# that sits on a log-grid boundary, moving that one element by at most
+# one grid step, itself at most the scale <= alpha / sqrt(1 - theta) ~
+# 32 * alpha. So: every element within 32 * alpha per step, and the
+# masters' movement from the initial weights agrees in RMS within 2^-6
+# (scale roundings) plus sqrt(flip fraction) (flips of at most their own
+# update size): at most 1e-3 of elements flipping gives sqrt(1e-3) ~
+# 0.032, 0.05 in all. A wrong code, scale or wire is off by O(1) there.
 EQUIV_LOSS_RTOL = 1e-3
-EQUIV_FLIP_FRACTION = 1e-3
+EQUIV_UPDATE_RTOL = 0.05
 
 
 class SmokeFailure(AssertionError):
@@ -273,24 +276,30 @@ def serve_phase(cfg, *, requests=8, slots=4, prompt_lens=(128, 512),
           f"all {requests} requests finished with {max_new} tokens")
     decode = sess.compiled_step()
 
-    prompt = max(reqs, key=lambda r: len(r.prompt)).prompt
-    tokens = {"tokens": jnp.asarray([prompt], jnp.int32)}
-
-    def prefill(fused):
+    def prefill(fused, tokens):
         ctx = ShardCtx(param_gather=make_dequant_gather(fused=fused))
         fn = jax.jit(lambda p, b: model.forward(p, b, ctx)[0])
         compiled = fn.lower(params, tokens).compile()
         return compiled, compiled(params, tokens).astype(jnp.float32)
 
-    fused_prefill, got_logits = prefill(True)
-    _, ref_logits = prefill(False)
-    diff = float(jnp.max(jnp.abs(got_logits - ref_logits)))
-    ref_max = float(jnp.max(jnp.abs(ref_logits)))
-    check(bool(jnp.all(jnp.isfinite(got_logits)))
-          and diff <= PREFILL_REL_TOL * ref_max,
-          f"code-resident prefill logits ({len(prompt)} tokens) agree with "
-          f"dequantize-then-float: max |diff| {diff:.4e} <= "
-          f"{PREFILL_REL_TOL} * max |logit| {ref_max:.4e}")
+    # the shortest and the longest prompt: two token streams, and two
+    # sequence lengths through attention
+    by_len = sorted(reqs, key=lambda r: len(r.prompt))
+    diffs, maxes = [], []
+    for prompt in (by_len[0].prompt, by_len[-1].prompt):
+        tokens = {"tokens": jnp.asarray([prompt], jnp.int32)}
+        fused_prefill, got_logits = prefill(True, tokens)
+        _, ref_logits = prefill(False, tokens)
+        diff = float(jnp.max(jnp.abs(got_logits - ref_logits)))
+        ref_max = float(jnp.max(jnp.abs(ref_logits)))
+        diffs.append(diff)
+        maxes.append(ref_max)
+        check(bool(jnp.all(jnp.isfinite(got_logits)))
+              and diff <= PREFILL_REL_TOL * ref_max,
+              f"code-resident prefill logits ({len(prompt)} tokens) agree "
+              f"with dequantize-then-float: max |diff| {diff:.4e} <= "
+              f"{PREFILL_REL_TOL} * max |logit| {ref_max:.4e} "
+              f"({diff / ref_max * 256:.2f} bf16 steps of 2^-8)")
     if expect_kernels:
         check_kernels(decode, ("dequant_matmul",), "decode step")
         check_kernels(fused_prefill, ("dequant_matmul",), "prefill")
@@ -298,8 +307,8 @@ def serve_phase(cfg, *, requests=8, slots=4, prompt_lens=(128, 512),
     print(f"  serve peak_bytes_in_use: {peak} (since the process started; "
           f"bytes_in_use at the serve phase's end {in_use_bytes()}, "
           f"resident weights {params_nbytes(params)} B)")
-    return {"tokens": got, "prefill_max_abs_diff": diff,
-            "prefill_max_abs_logit": ref_max, "peak_bytes_in_use": peak}
+    return {"tokens": got, "prefill_max_abs_diff": diffs,
+            "prefill_max_abs_logit": maxes, "peak_bytes_in_use": peak}
 
 
 def four_worker_phase(cfg, *, workers=4, seq=TRAIN_SEQ, steps=3,
@@ -355,6 +364,8 @@ def four_worker_phase(cfg, *, workers=4, seq=TRAIN_SEQ, steps=3,
         return apply_updates(params, upd), ostate, loss
 
     params = model.init(jax.random.PRNGKey(0))
+    init = [np.asarray(x, np.float32).reshape(-1)
+            for x in jax.tree.leaves(params)]
     ostate = opt.init(params)
     ref_losses = []
     for i in range(steps):
@@ -362,31 +373,37 @@ def four_worker_phase(cfg, *, workers=4, seq=TRAIN_SEQ, steps=3,
         ref_losses.append(float(loss))
         print(f"  Algorithm 1 step {i + 1} loss {ref_losses[-1]:.4f}")
 
-    flips = total = 0
-    worst = 0.0
-    for got, ref in zip(master, jax.tree.leaves(params)):
+    differ = total = 0
+    worst = sq_diff = sq_moved = 0.0
+    for got, ref, x0 in zip(master, jax.tree.leaves(params), init):
         ref = np.asarray(ref, np.float32).reshape(-1)
         got = got.reshape(-1)[:ref.size]     # (workers, 1, c) chunks
         d = np.abs(got - ref)
         worst = max(worst, float(d.max()))
-        flips += int((d > 1e-6).sum())
+        differ += int((d > 1e-6).sum())
         total += ref.size
+        sq_diff += float(np.sum(d.astype(np.float64) ** 2))
+        sq_moved += float(np.sum((ref - x0).astype(np.float64) ** 2))
+    rel = math.sqrt(sq_diff / sq_moved)
     print(f"  {workers}-worker losses:   {dist_losses}")
     print(f"  Algorithm 1 losses: {ref_losses}")
     print(f"  per-device peak_bytes_in_use (after the {workers}-worker "
           f"steps): {peaks}")
-    print(f"  masters: max |diff| {worst:.3e}, {flips} of {total} elements "
-          f"differ by more than 1e-6")
+    print(f"  masters: max |diff| {worst:.3e}, {differ} of {total} elements "
+          f"differ by more than 1e-6; RMS diff / RMS movement from the "
+          f"initial weights {rel:.3e}")
     check(all(abs(a - b) <= EQUIV_LOSS_RTOL * abs(b)
               for a, b in zip(dist_losses, ref_losses)),
           f"losses agree within relative {EQUIV_LOSS_RTOL}")
-    check(worst <= 32 * alpha * steps and
-          flips <= EQUIV_FLIP_FRACTION * total,
-          f"masters agree: max |diff| <= {32 * alpha * steps:.3e} and at "
-          f"most {EQUIV_FLIP_FRACTION} of elements differ by more than 1e-6")
+    check(worst <= 32 * alpha * steps,
+          f"masters agree elementwise: max |diff| {worst:.3e} <= "
+          f"{32 * alpha * steps:.3e}")
+    check(rel <= EQUIV_UPDATE_RTOL,
+          f"the masters' movement agrees: RMS diff / RMS movement "
+          f"{rel:.3e} <= {EQUIV_UPDATE_RTOL}")
     return {"losses": dist_losses, "ref_losses": ref_losses,
-            "master_max_abs_diff": worst, "master_flips": flips,
-            "peak_bytes_in_use": peaks}
+            "master_max_abs_diff": worst, "master_differ": differ,
+            "master_update_rel_diff": rel, "peak_bytes_in_use": peaks}
 
 
 def main(argv=None):

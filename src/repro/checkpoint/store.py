@@ -16,8 +16,10 @@ Optional codec compression (``save(..., codec="uniform_amax:7")``):
 leaves under the ``codec_keys`` top-level keys (default: the optimizer
 moments m/v/e) are stored as ``repro.comm`` wire buffers - packed codes
 + scales - instead of raw f32, cutting moment snapshots ~4x at k_x=7.
-The manifest records the codec spec per leaf; ``restore`` decodes
-transparently. (Lossy by construction - exactly the quantizer's grid
+The manifest records the codec spec and the packed byte layout
+(``repro.comm.bits.LAYOUT``) per leaf; ``restore`` decodes
+transparently, and refuses a leaf packed in another layout rather than
+decode it wrongly. (Lossy by construction - exactly the quantizer's grid
 error; master weights and counters always stay exact.)
 """
 from __future__ import annotations
@@ -91,6 +93,7 @@ def _write_payload(d: str, tree: Any, step: Optional[int],
     os.makedirs(d, exist_ok=True)
     if codec is not None:
         from repro import comm
+        from repro.comm.bits import LAYOUT
         cd = comm.get_codec(codec)
     keys, vals, _ = _flatten(tree)
     arrays = {}
@@ -108,7 +111,7 @@ def _write_payload(d: str, tree: Any, step: Optional[int],
             arrays[f"{name}_scale"] = np.asarray(jax.device_get(wb.scale))
             manifest["leaves"].append(
                 {"key": k, "name": name, "dtype": str(arr.dtype),
-                 "shape": shape, "codec": cd.spec})
+                 "shape": shape, "codec": cd.spec, "layout": LAYOUT})
             continue
         # store raw bytes: npz mangles non-native dtypes (bfloat16 -> |V2)
         arrays[name] = arr.view(np.uint8).reshape(-1)
@@ -174,6 +177,13 @@ def restore(path: str, like: Any, shardings: Any = None,
         dt = np.dtype(ent["dtype"])
         if ent.get("codec"):
             from repro import comm
+            from repro.comm.bits import LAYOUT
+            if ent.get("layout") != LAYOUT:
+                raise ValueError(
+                    f"{d}: leaf {k!r} was packed in wire layout "
+                    f"{ent.get('layout', 1)}; this code reads layout "
+                    f"{LAYOUT} - restore it with the code that wrote it "
+                    f"and save it again")
             wb = comm.WireBuffer(
                 payload=jnp.asarray(raw),
                 scale=jnp.asarray(data[f"{ent['name']}_scale"]),

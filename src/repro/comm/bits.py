@@ -71,6 +71,13 @@ def _bias(bits: int) -> int:
 # ``payload_nbytes`` bytes.
 PLANE = 128
 
+# Version of the byte layout above. Anything that stores payloads apart
+# from the code that packed them (codec-compressed checkpoints, compiled
+# steps kept on disk) records it and refuses another: 1 put a group's
+# codes side by side and each 16-bit lane's two bytes together; 2 puts
+# them PLANE apart in byte planes.
+LAYOUT = 2
+
 
 def block_codes(bits: int) -> int:
     """Codes in one whole packing block (``group_codes`` planes)."""
@@ -82,29 +89,55 @@ def block_nbytes(bits: int) -> int:
     return group_nbytes(bits) * PLANE
 
 
+def lane_values(codes, bits: int):
+    """Signed codes -> the unsigned lane values a group packs (int32)."""
+    return (codes.astype(jnp.int32) + _bias(bits)) & ((1 << bits) - 1)
+
+
+def signed_codes(u, bits: int):
+    """Inverse of :func:`lane_values` -> codes (int8, or int16 for
+    16-bit lanes)."""
+    if bits < 8:
+        codes = u - _bias(bits)
+    else:
+        half = 1 << (bits - 1)
+        codes = ((u + half) & ((1 << bits) - 1)) - half
+    return codes.astype(jnp.int16 if bits == 16 else jnp.int8)
+
+
+def pack_planes(planes, bits: int):
+    """The ``group_codes`` lane-value planes of a group (equal shapes,
+    int32) -> its ``group_nbytes`` byte planes (int32 values 0..255).
+    Plane-wise, so a kernel can feed it planes it read with a stride."""
+    val = planes[0]
+    for j in range(1, group_codes(bits)):   # <= 24 bits per group
+        val = val | (planes[j] << (j * bits))
+    return [(val >> (8 * b)) & 0xFF for b in range(group_nbytes(bits))]
+
+
+def unpack_planes(byte_planes, bits: int):
+    """Inverse of :func:`pack_planes` -> ``group_codes`` lane-value
+    planes (int32)."""
+    val = byte_planes[0]
+    for b in range(1, group_nbytes(bits)):
+        val = val | (byte_planes[b] << (8 * b))
+    mask = (1 << bits) - 1
+    return [(val >> (j * bits)) & mask for j in range(group_codes(bits))]
+
+
 def _pack_block(u, bits: int, w: int):
     """(..., g*w) lane values -> (..., nb*w) byte values (int32)."""
-    g, nb = group_codes(bits), group_nbytes(bits)
-    val = u[..., 0:w]
-    for j in range(1, g):  # <= 24 bits per group, fits int32
-        val = val | (u[..., j * w:(j + 1) * w] << (j * bits))
-    if nb == 1:
-        return val & 0xFF
-    return jnp.concatenate([(val >> (8 * b)) & 0xFF for b in range(nb)],
-                           axis=-1)
+    g = group_codes(bits)
+    out = pack_planes([u[..., j * w:(j + 1) * w] for j in range(g)], bits)
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=-1)
 
 
 def _unpack_block(p, bits: int, w: int):
     """(..., nb*w) byte values -> (..., g*w) lane values (int32)."""
-    g, nb = group_codes(bits), group_nbytes(bits)
-    val = p[..., 0:w]
-    for b in range(1, nb):
-        val = val | (p[..., b * w:(b + 1) * w] << (8 * b))
-    if g == 1:
-        return val
-    mask = (1 << bits) - 1
-    return jnp.concatenate([(val >> (j * bits)) & mask for j in range(g)],
-                           axis=-1)
+    nb = group_nbytes(bits)
+    out = unpack_planes([p[..., b * w:(b + 1) * w] for b in range(nb)],
+                        bits)
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=-1)
 
 
 def _by_blocks(x, fn, per_in: int):
@@ -130,7 +163,7 @@ def pack_lanes(codes2d: jax.Array, bits: int) -> jax.Array:
         raise ValueError(f"bits={bits} not in {SUPPORTED_BITS}")
     L = codes2d.shape[1]
     assert L % group_codes(bits) == 0, (L, bits)
-    u = (codes2d.astype(jnp.int32) + _bias(bits)) & ((1 << bits) - 1)
+    u = lane_values(codes2d, bits)
     out = _by_blocks(u, lambda b, w: _pack_block(b, bits, w),
                      block_codes(bits))
     return out.astype(jnp.uint8)
@@ -146,12 +179,7 @@ def unpack_lanes(payload2d: jax.Array, bits: int, L: int) -> jax.Array:
                    lambda b, w: _unpack_block(b, bits, w), block_nbytes(bits))
     if u.shape[1] != L:
         u = u[:, :L]
-    if bits < 8:
-        codes = u - _bias(bits)
-    else:
-        half = 1 << (bits - 1)
-        codes = ((u + half) & ((1 << bits) - 1)) - half
-    return codes.astype(jnp.int16 if bits == 16 else jnp.int8)
+    return signed_codes(u, bits)
 
 
 # ---------------------------------------------------------------------------

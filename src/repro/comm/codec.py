@@ -129,9 +129,11 @@ def _row_blocks(c: int, bits: int) -> int:
 
 
 def _kernel_rows(rows: jax.Array, bits: int) -> jax.Array:
-    """(R, c) values -> (R * _row_blocks(c), lanes_in) kernel tiles."""
+    """(R, c) values -> (R * _row_blocks(c) * planes, 128) kernel tiles:
+    the whole-block rows' own row-major order, 128 lanes wide, so the
+    reshape moves no data."""
     nblk = _row_blocks(rows.shape[1], bits)
-    return B.to_blocks(rows, bits, nblk).reshape(-1, K.lanes_in(bits))
+    return B.to_blocks(rows, bits, nblk).reshape(-1, K.LANES)
 
 
 # ---------------------------------------------------------------------------

@@ -23,11 +23,18 @@ Every kernel body calls the canonical math in ``repro.opt.grids`` and
 to the jnp reference backend by construction (asserted across all lane
 widths by ``tests/test_comm_codecs.py``).
 
-Lane geometry: every tile row is one whole packing block
-(``repro.comm.bits.block_codes``): ``LANES_IN[bits]`` codes read as
-planes of 128 lanes, packed to byte planes of 128 lanes (e.g. 3-bit
-lanes read (rows, 1024) floats and write (rows, 384) bytes). Packing
-then only slices whole vregs, which is what the TPU compiler lowers.
+Lane geometry: every payload row is one whole packing block
+(``repro.comm.bits.block_codes``): ``group_codes`` planes of 128 codes,
+packed to ``group_nbytes`` byte planes of 128 lanes (e.g. 3-bit lanes
+read 8 planes of floats and write (rows, 384) bytes). The float side is
+an array of 128-lane rows, ``group_codes`` rows per block, which a
+kernel reads and writes plane by plane with a sublane stride: packing
+then only touches whole vregs, which is what the TPU compiler lowers,
+and the array is the flat tensor's own row-major layout, so reshaping
+between it and the tensor's shape moves no data. (A 256-lane or wider
+float array is tiled differently from a flat one: XLA must relayout
+it, and at yi-6b widths it emitted tens of MB of unrolled code per
+leaf for that copy.)
 
 The historical per-op kernels (separate amax / quantize / dequantize
 passes) also live here now; ``repro.kernels.quantize`` and
@@ -89,6 +96,11 @@ def lanes_in(bits: int) -> int:
     return LANES_IN[bits]
 
 
+def planes(bits: int) -> int:
+    """128-lane float rows per packing block (``group_codes``)."""
+    return B.group_codes(bits)
+
+
 def lanes_out(bits: int) -> int:
     return LANES_IN[bits] * bits // 8
 
@@ -98,6 +110,42 @@ def smem():
     hyper-parameters, amax) live in scalar memory on TPU - a vector
     memory block cannot hold or store a scalar."""
     return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _read_planes(ref, g: int):
+    """(rows * g, 128) float block -> its g planes, each (rows, 128):
+    plane j of block row r is row ``r * g + j``."""
+    if g == 1:
+        return [ref[...]]
+    rows = ref.shape[0] // g
+    return [ref[pl.ds(j, rows, stride=g), :] for j in range(g)]
+
+
+def _write_planes(ref, planes_):
+    """Inverse of :func:`_read_planes`."""
+    g = len(planes_)
+    if g == 1:
+        ref[...] = planes_[0]
+        return
+    rows = ref.shape[0] // g
+    for j, p in enumerate(planes_):
+        ref[pl.ds(j, rows, stride=g), :] = p
+
+
+def _pack_codes(code_planes, bits):
+    """Code planes -> (rows, lanes_out) uint8 payload tile."""
+    out = B.pack_planes([B.lane_values(c, bits) for c in code_planes], bits)
+    return (out[0] if len(out) == 1
+            else jnp.concatenate(out, axis=-1)).astype(jnp.uint8)
+
+
+def _unpack_codes(payload, bits):
+    """(rows, lanes_out) uint8 payload tile -> code planes."""
+    p = payload.astype(jnp.int32)
+    nb = B.group_nbytes(bits)
+    byte_planes = [p[:, b * LANES:(b + 1) * LANES] for b in range(nb)]
+    return [B.signed_codes(u, bits) for u in B.unpack_planes(byte_planes,
+                                                              bits)]
 
 
 def lut_dequant(codes, scale, lut_ref):
@@ -192,18 +240,21 @@ def _encode2_body(*refs, kind, bits, k, clip_abs):
         def _():
             scale_ref[0] = scale
 
-        u = u_ref[...] if u_ref is not None else None
-        codes = _quant(x_ref[...], scale, u, kind=kind, k=k,
-                       clip_abs=clip_abs)
-        payload_ref[...] = B.pack_lanes(codes, bits)
+        g = planes(bits)
+        us = (_read_planes(u_ref, g) if u_ref is not None
+              else [None] * g)
+        payload_ref[...] = _pack_codes(
+            [_quant(x, scale, u, kind=kind, k=k, clip_abs=clip_abs)
+             for x, u in zip(_read_planes(x_ref, g), us)], bits)
 
 
 def _encode1_body(x_ref, scale_ref, payload_ref, *, kind, bits, k,
                   clip_abs):
     """Single-phase encode with a known scale (absolute grids)."""
-    codes = _quant(x_ref[...], scale_ref[0], None, kind=kind, k=k,
-                   clip_abs=clip_abs)
-    payload_ref[...] = B.pack_lanes(codes, bits)
+    s = scale_ref[0]
+    payload_ref[...] = _pack_codes(
+        [_quant(x, s, None, kind=kind, k=k, clip_abs=clip_abs)
+         for x in _read_planes(x_ref, planes(bits))], bits)
 
 
 def encode_pallas(x2d: jax.Array, kind: str, bits: int, k: int, *,
@@ -211,17 +262,20 @@ def encode_pallas(x2d: jax.Array, kind: str, bits: int, k: int, *,
                   interpret: bool):
     """Fused amax+quantize+pack, ONE ``pallas_call``.
 
-    x2d: (R, LANES_IN[bits]) f32, R a multiple of ENC_ROWS, each row one
-    whole packing block (``repro.comm.bits.to_blocks``). Returns
-    ``(payload2d uint8 (R, lanes_out), scale ())``; with ``scale=`` given
-    the amax phase is skipped and the same scale is returned.
+    x2d: (R * planes(bits), 128) f32, R a multiple of ENC_ROWS, every
+    ``planes(bits)`` rows one whole packing block
+    (``repro.comm.bits.to_blocks``). Returns ``(payload2d uint8
+    (R, lanes_out), scale ())``; with ``scale=`` given the amax phase is
+    skipped and the same scale is returned.
     """
-    rows = x2d.shape[0]
+    g = planes(bits)
+    rows = x2d.shape[0] // g
     er = enc_rows()
-    li, lo = lanes_in(bits), lanes_out(bits)
-    assert x2d.shape[1] == li and rows % er == 0, (x2d.shape, bits)
+    lo = lanes_out(bits)
+    assert x2d.shape[1] == LANES and x2d.shape[0] == rows * g \
+        and rows % er == 0, (x2d.shape, bits)
     nb = rows // er
-    xblk = pl.BlockSpec((er, li), lambda p, i: (i, 0))
+    xblk = pl.BlockSpec((er * g, LANES), lambda p, i: (i, 0))
     payload_shape = jax.ShapeDtypeStruct((rows, lo), jnp.uint8)
 
     if scale is not None:
@@ -273,9 +327,9 @@ def _decode_body(*refs, kind, bits, k, out_dtype, tiles_per_scale,
         (payload_ref, scale_ref, o_ref), lut_ref = refs, None
     t = tiles_per_scale
     s = scale_ref[pl.program_id(0) // t] if t else scale_ref[0]
-    codes = B.unpack_lanes(payload_ref[...], bits, o_ref.shape[-1])
-    o_ref[...] = _kdequant(codes, s, kind=kind, k=k,
-                           lut_ref=lut_ref).astype(out_dtype)
+    _write_planes(o_ref, [
+        _kdequant(c, s, kind=kind, k=k, lut_ref=lut_ref).astype(out_dtype)
+        for c in _unpack_codes(payload_ref[...], bits)])
 
 
 def decode_pallas(payload2d: jax.Array, scales: jax.Array, kind: str,
@@ -284,15 +338,16 @@ def decode_pallas(payload2d: jax.Array, scales: jax.Array, kind: str,
                   interpret: bool) -> jax.Array:
     """Fused unpack+dequantize, ONE ``pallas_call``.
 
-    payload2d: (R, lanes_out(bits)) uint8. ``scales`` is either a scalar
-    (per-tensor) or a (n_rows,) vector with ``tiles_per_scale`` grid
-    steps per wire row (the per-source-worker scales of the dist
+    payload2d: (R, lanes_out(bits)) uint8; returns (R * planes(bits),
+    128) values in ``encode_pallas``'s input layout. ``scales`` is either
+    a scalar (per-tensor) or a (n_rows,) vector with ``tiles_per_scale``
+    grid steps per wire row (the per-source-worker scales of the dist
     channels). ``lut`` (log grid only) is the (2^bits,) scale-1 dequant
     table from ``grids.log_dequant_table``; it rides in SMEM.
     """
     rows = payload2d.shape[0]
     er = enc_rows()
-    li, lo = lanes_in(bits), lanes_out(bits)
+    g, lo = planes(bits), lanes_out(bits)
     assert payload2d.shape[1] == lo and rows % er == 0
     scales = jnp.asarray(scales, jnp.float32).reshape(-1)
     operands = [payload2d, scales]
@@ -307,8 +362,8 @@ def decode_pallas(payload2d: jax.Array, scales: jax.Array, kind: str,
                           has_lut=lut is not None),
         grid=(rows // er,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((er, li), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, li), out_dtype),
+        out_specs=pl.BlockSpec((er * g, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows * g, LANES), out_dtype),
         interpret=interpret,
         name="codec_decode",
     )(*operands)
@@ -325,24 +380,31 @@ def _ef_encode_body(*refs, kind, bits, k, clip_abs, has_lut):
         x_ref, scale_ref, lut_ref, payload_ref, e_ref = refs
     else:
         (x_ref, scale_ref, payload_ref, e_ref), lut_ref = refs, None
-    x = x_ref[...]
     s = scale_ref[0]
-    codes = _quant(x, s, None, kind=kind, k=k, clip_abs=clip_abs)
-    payload_ref[...] = B.pack_lanes(codes, bits)
-    e_ref[...] = x - _kdequant(codes, s, kind=kind, k=k, lut_ref=lut_ref)
+    xs = _read_planes(x_ref, planes(bits))
+    codes = [_quant(x, s, None, kind=kind, k=k, clip_abs=clip_abs)
+             for x in xs]
+    payload_ref[...] = _pack_codes(codes, bits)
+    _write_planes(e_ref, [
+        x - _kdequant(c, s, kind=kind, k=k, lut_ref=lut_ref)
+        for x, c in zip(xs, codes)])
 
 
 def ef_encode_pallas(x2d: jax.Array, scale: jax.Array, kind: str,
                      bits: int, k: int, *, clip_abs=None, lut=None,
                      interpret: bool):
     """(x, scale) -> (packed payload, EF residual e' = x - deq(codes)),
-    one launch. The codes never leave VMEM."""
-    rows = x2d.shape[0]
+    one launch, in ``encode_pallas``'s layouts (the residual in x2d's).
+    The codes never leave VMEM."""
+    g = planes(bits)
+    rows = x2d.shape[0] // g
     er = enc_rows()
-    li, lo = lanes_in(bits), lanes_out(bits)
-    assert x2d.shape[1] == li and rows % er == 0
+    lo = lanes_out(bits)
+    assert x2d.shape[1] == LANES and x2d.shape[0] == rows * g \
+        and rows % er == 0
     operands = [x2d, jnp.asarray(scale, jnp.float32).reshape(1)]
-    in_specs = [pl.BlockSpec((er, li), lambda i: (i, 0)), smem()]
+    xblk = pl.BlockSpec((er * g, LANES), lambda i: (i, 0))
+    in_specs = [xblk, smem()]
     if lut is not None:
         operands.append(jnp.asarray(lut, jnp.float32))
         in_specs.append(smem())
@@ -351,10 +413,9 @@ def ef_encode_pallas(x2d: jax.Array, scale: jax.Array, kind: str,
                           clip_abs=clip_abs, has_lut=lut is not None),
         grid=(rows // er,),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((er, lo), lambda i: (i, 0)),
-                   pl.BlockSpec((er, li), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((er, lo), lambda i: (i, 0)), xblk],
         out_shape=[jax.ShapeDtypeStruct((rows, lo), jnp.uint8),
-                   jax.ShapeDtypeStruct((rows, li), jnp.float32)],
+                   jax.ShapeDtypeStruct(x2d.shape, jnp.float32)],
         interpret=interpret,
         name="codec_ef_encode",
     )(*operands)

@@ -52,6 +52,26 @@ def wire_bits_for_log(k_g: int) -> int:
 amax_scale = grids.amax_scale  # shared zero-guarded scale (one definition)
 
 
+LANES = 128
+
+
+def lane_view(x: jax.Array, lead: int = 0) -> jax.Array:
+    """View the trailing axis of ``x`` (after ``lead`` leading axes) as
+    rows of 128 lanes when it divides: ``(..., n) -> (..., n/128, 128)``.
+
+    A TPU tiles the two minor axes of an array in (8, 128) tiles, so a
+    few long rows - ``(n_workers, c)`` wire rows - are laid out unlike
+    the flat tensors and kernel tiles they come from and go to: XLA
+    copies them element by element, and at yi-6b widths that copy was
+    tens of MB of unrolled code per leaf. In the lane view every row is
+    a run of whole tiles, so the reshapes on either side move no data.
+    Values and order are unchanged; a row that does not divide stays as
+    it is."""
+    if x.ndim != lead + 1 or x.shape[-1] % LANES or x.shape[-1] == LANES:
+        return x
+    return x.reshape(x.shape[:-1] + (x.shape[-1] // LANES, LANES))
+
+
 # ---------------------------------------------------------------------------
 # worker-axis collectives (inside shard_map)
 # ---------------------------------------------------------------------------
@@ -67,10 +87,10 @@ def worker_index(axes: Sequence[str], sizes: Sequence[int]) -> jax.Array:
 def gather_rows(x: jax.Array, axes: Sequence[str]) -> jax.Array:
     """All-gather one per-worker value -> (n_workers, *x.shape), rows in
     flat worker order (same order as worker_index)."""
-    r = x[None]
+    r = lane_view(x)[None]
     for a in reversed(tuple(axes)):
         r = jax.lax.all_gather(r, a, axis=0, tiled=True)
-    return r
+    return r.reshape(r.shape[:1] + x.shape)
 
 
 def exchange_rows(rows: jax.Array, axes: Sequence[str],
@@ -82,7 +102,8 @@ def exchange_rows(rows: jax.Array, axes: Sequence[str],
     if not axes:
         return rows
     nw = int(np.prod(sizes))
-    x = rows.reshape(tuple(sizes) + rows.shape[1:])
+    x = lane_view(rows, 1)
+    x = x.reshape(tuple(sizes) + x.shape[1:])
     for i, a in enumerate(axes):
         x = jax.lax.all_to_all(x, a, split_axis=i, concat_axis=i)
     return x.reshape((nw,) + rows.shape[1:])

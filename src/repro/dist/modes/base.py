@@ -132,14 +132,17 @@ def worker_mean(rows):
     """Mean over worker rows via pairwise (tree) summation: with n a
     power of two and identical rows (the paper's identical-worker
     equivalence), the result is bit-exact - a sequential reduce
-    (((x+x)+x)+x) is not, and its ulp bias flips quantizer codes."""
+    (((x+x)+x)+x) is not, and its ulp bias flips quantizer codes. The
+    rows are summed in their 128-lane view (``collectives.lane_view``),
+    which moves no data on a TPU; the values are the same."""
     def psum_rows(x):
         k = x.shape[0]
         if k == 1:
             return x[0]
         h = k // 2
         return psum_rows(x[:h]) + psum_rows(x[h:])
-    return psum_rows(rows) / rows.shape[0]
+    mean = psum_rows(C.lane_view(rows, 1)) / rows.shape[0]
+    return mean.reshape(rows.shape[1:])
 
 
 def tier_grad_mean(g, tiers: Optional[Tiers]):

@@ -28,7 +28,9 @@ from typing import Any, Optional
 import jax
 from jax.experimental import serialize_executable as _se
 
-FORMAT = 1
+# 2: keys name the packed wire layout (repro.comm.bits.LAYOUT), which
+# a compiled step bakes in
+FORMAT = 2
 SUFFIX = ".aotstep"
 
 
@@ -69,12 +71,15 @@ def _abstract(tree) -> Any:
 
 
 def _device_facts() -> dict:
+    from repro.comm.bits import LAYOUT
     devs = jax.devices()
     return {
         "backend": jax.default_backend(),
         "device_kind": devs[0].device_kind,
         "n_devices": len(devs),
         "jax": jax.__version__,
+        "format": FORMAT,
+        "wire_layout": LAYOUT,
     }
 
 

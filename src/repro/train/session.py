@@ -683,7 +683,7 @@ class TrainSession:
                 args = (self._state, self._ring, sl, batch)
             else:
                 args = (self._state, self._ring, self._sring, sl, batch)
-            self._last_dispatch = (k, jax.tree.map(_abstract, args))
+            self._last_dispatch = (k, batch)
             out = self._built_step(k, args)(*args)
             if self._sring is None:
                 self._state, self._ring = out
@@ -737,7 +737,12 @@ class TrainSession:
         process, so nothing is compiled again."""
         if self._last_dispatch is None:
             raise RuntimeError("no step has been dispatched yet")
-        k, sig = self._last_dispatch
+        k, batch = self._last_dispatch
+        # the live state and rings have the donated inputs' shapes
+        rings = ((self._ring,) if self._sring is None
+                 else (self._ring, self._sring))
+        sig = jax.tree.map(_abstract,
+                           (self._state, *rings, self._slot, batch))
         fn = self._built_step(k, sig)
         return fn if hasattr(fn, "as_text") else fn.lower(*sig).compile()
 

@@ -9,12 +9,16 @@ the compiled program. The topology is described inside a module fixture,
 so every test worker collects the same tests and only the worker that
 runs this file loads the TPU library.
 """
+import dataclasses
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.experimental.shard_map import shard_map
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro.comm import bits as B
 from repro.comm import kernels as K
@@ -25,6 +29,14 @@ from repro.serve import paged
 
 D_MODEL, D_FF, KV_HEADS, HEAD_DIM = 4096, 11008, 4, 128
 LEAF = D_MODEL * D_FF          # one MLP projection
+# generated code of one leaf's exchange, and of a whole one-layer step.
+# A wire row or kernel tile laid out unlike the flat tensor makes XLA
+# copy it with unrolled code: 68-70 MB per leaf and 746 MB for the
+# four-device step at these widths, which took five minutes and 25 GB
+# of host memory to compile. Without such copies they are 0.5-0.8 MB
+# and 31-35 MB.
+LEAF_CODE_BYTES = 8 << 20
+STEP_CODE_BYTES = 96 << 20
 
 
 @pytest.fixture(scope="module")
@@ -39,16 +51,29 @@ def topo():
 
 
 @pytest.fixture(scope="module")
-def one_chip(topo):
+def no_cache(topo):
     # a compile for a described chip is written to the persistent cache
     # but cannot be read back without one: keep the cache out of it
     from jax.experimental.compilation_cache import compilation_cache
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield
     jax.config.update("jax_enable_compilation_cache", prev)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_cache):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def tpu_paths(monkeypatch):
+    """Steer the auto backends (``jax.default_backend()`` still names
+    the CPU here) to the paths they take on a TPU: Pallas kernels,
+    compiled rather than interpreted."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
 def _compile(fn, *args):
@@ -88,8 +113,9 @@ def test_fused_dequant_matmul(one_chip, k, n, k_x):
 
 
 def _codec_rows(bits):
-    li = K.lanes_in(bits)
-    return -(-LEAF // (li * K.ENC_ROWS)) * K.ENC_ROWS, li
+    """Payload rows of one LEAF, and the rows of its 128-lane floats."""
+    rows = -(-LEAF // (K.lanes_in(bits) * K.ENC_ROWS)) * K.ENC_ROWS
+    return rows, rows * K.planes(bits)
 
 
 @pytest.mark.parametrize("kind,bits,k,static", [
@@ -98,23 +124,23 @@ def _codec_rows(bits):
     ("uniform", 8, 6, True),       # the paper's absolute Q_x grid
 ])
 def test_codec_encode(one_chip, kind, bits, k, static):
-    rows, li = _codec_rows(bits)
+    _, frows = _codec_rows(bits)
     scale = jnp.float32(0.5) if static else None
     text = _compile(
         lambda x: K.encode_pallas(x, kind, bits, k, scale=scale,
                                   interpret=False),
-        _sds(one_chip, (rows, li), jnp.float32))
+        _sds(one_chip, (frows, K.LANES), jnp.float32))
     assert _has_kernel(text, "codec_encode")
 
 
 @pytest.mark.parametrize("kind,bits,k", [("log", 4, 4), ("uniform", 8, 6)])
 def test_codec_ef_encode(one_chip, kind, bits, k):
-    rows, li = _codec_rows(bits)
+    _, frows = _codec_rows(bits)
     lut = grids.log_dequant_table(k, bits) if kind == "log" else None
     text = _compile(
         lambda x, s: K.ef_encode_pallas(x, s, kind, bits, k, lut=lut,
                                         interpret=False),
-        _sds(one_chip, (rows, li), jnp.float32),
+        _sds(one_chip, (frows, K.LANES), jnp.float32),
         _sds(one_chip, (), jnp.float32))
     assert _has_kernel(text, "codec_ef_encode")
 
@@ -161,3 +187,76 @@ def test_paged_gather(one_chip):
              jnp.bfloat16),
         _sds(one_chip, (slots, pages_per_slot), jnp.int32))
     assert _has_kernel(text, "paged_gather")
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+def test_exchange_leaf_code_size(topo, no_cache, tpu_paths, workers):
+    """One MLP leaf through the paper's exchange (Adam+EF, Q_g encode,
+    all-to-all, decode, mean) on ``workers`` described chips, state in
+    and out in the train state's (1, 1, X) layout."""
+    from repro.dist import topology as T
+    from repro.dist.modes import WorkerCtx, get_mode
+    from repro.dist.step import LeafMeta, TrainConfig
+    mesh = Mesh(np.array(topo.devices[:workers]).reshape(workers, 1),
+                ("data", "model"))
+    c = -(-LEAF // workers)
+    tc = TrainConfig(grad_k=4, weight_k=6, worker_axes=("data",))
+    upd = get_mode("qadam").make_updater(tc, WorkerCtx(
+        worker_axes=("data",), wsizes=(workers,), n_workers=workers,
+        tiers=T.flat_tiers(("data",), (workers,))))
+    meta = LeafMeta(shp=(D_MODEL, D_FF), c=c, numel=LEAF, dim=0,
+                    stacked=False, shape=(D_MODEL, D_FF))
+
+    def leaf(g, m, v, e, chunk):
+        out = upd(g.reshape(-1), m.reshape(-1), v.reshape(-1),
+                  e.reshape(-1), chunk.reshape(-1), meta, 1e-3, 0.999,
+                  jax.random.PRNGKey(0), 0)
+        return tuple(x.reshape(1, 1, -1) for x in out)
+
+    spec = P("data", "model", None)
+    fn = shard_map(leaf, mesh=mesh, in_specs=(spec,) * 5,
+                   out_specs=(spec,) * 4, check_rep=False)
+    sh = NamedSharding(mesh, spec)
+    arg = lambda x: jax.ShapeDtypeStruct((workers, 1, x), jnp.float32,
+                                         sharding=sh)
+    compiled = jax.jit(fn).lower(arg(LEAF), arg(LEAF), arg(LEAF),
+                                 arg(LEAF), arg(c)).compile()
+    text = compiled.as_text()
+    for name in ("adam_ef_moments", "codec_ef_encode", "codec_decode"):
+        assert _has_kernel(text, name)
+    code = compiled.memory_analysis().generated_code_size_in_bytes
+    assert code < LEAF_CODE_BYTES, code
+
+
+def test_four_worker_train_step(topo, no_cache, tpu_paths):
+    """The four-worker QAdam-EF step of ``chip_smoke.py --chips 4`` at
+    yi-6b widths (one layer, a 4,096-row vocabulary) compiles for a
+    described v5e 2x2 with its kernels, bounded code and its state
+    spread over the four chips."""
+    from repro.configs import get_config
+    from repro.dist.step import (TrainConfig, batch_shardings,
+                                 make_train_step, state_template)
+    from repro.models.model import Model
+    cfg = dataclasses.replace(get_config("yi-6b"), n_layers=1,
+                              vocab_size=4096)
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    art = make_train_step(Model(cfg), mesh, TrainConfig(
+        grad_k=4, weight_k=6, worker_axes=("pod", "data")))
+    seq = 2048
+    batch = {"tokens": np.zeros((4, seq), np.int32),
+             "targets": np.zeros((4, seq), np.int32),
+             "mask": np.zeros((4, seq), np.float32)}
+    shard = batch_shardings(art, batch)
+    batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=shard[k])
+             for k, v in batch.items()}
+    compiled = jax.jit(art.step_fn, donate_argnums=(0,)).lower(
+        state_template(art), batch).compile()
+    text = compiled.as_text()
+    for name in ("adam_ef_moments", "codec_ef_encode", "codec_decode"):
+        assert _has_kernel(text, name)
+    mem = compiled.memory_analysis()
+    assert mem.generated_code_size_in_bytes < STEP_CODE_BYTES, \
+        mem.generated_code_size_in_bytes
+    # per device: a quarter of the fp32 masters, but m, v and e of the
+    # whole model (Algorithm 3) - well under one chip's 16 GB
+    assert mem.argument_size_in_bytes < 4 << 30

@@ -88,6 +88,32 @@ class TestAotRoundtrip:
         np.testing.assert_array_equal(np.asarray(fn(*args)),
                                       np.asarray(jitted(*args)))
 
+    def test_artifact_of_another_format_is_a_miss(self, tmp_path):
+        """An artifact written under another FORMAT (e.g. before the
+        packed wire layout changed) recompiles instead of loading."""
+        import pickle
+        jitted = jax.jit(lambda x: x + 1)
+        args = (jnp.ones(4),)
+        aot.load_or_compile(jitted, args, aot_dir=str(tmp_path),
+                            facts="f", stats=None)
+        [path] = glob.glob(str(tmp_path / ("*" + aot.SUFFIX)))
+        with open(path, "rb") as f:
+            art = pickle.load(f)
+        art["format"] = aot.FORMAT - 1
+        with open(path, "wb") as f:
+            pickle.dump(art, f)
+        stats = {}
+        aot.load_or_compile(jitted, args, aot_dir=str(tmp_path),
+                            facts="f", stats=stats)
+        assert stats == {"compilations": 1, "aot_saves": 1}
+
+    def test_key_names_the_wire_layout(self, monkeypatch):
+        from repro.comm import bits
+        args = (jnp.ones(4),)
+        key = aot.step_key("f", args)
+        monkeypatch.setattr(bits, "LAYOUT", bits.LAYOUT + 1)
+        assert aot.step_key("f", args) != key
+
     def test_no_dir_passthrough(self):
         jitted = jax.jit(lambda x: x * 2)
         stats = {}

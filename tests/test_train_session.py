@@ -257,6 +257,30 @@ class TestCheckpointStore:
         np.testing.assert_array_equal(np.asarray(out["w"]),
                                       np.arange(8, dtype=np.float32) + 6)
 
+    def test_codec_leaves_record_the_wire_layout(self, tmp_path):
+        """Codec-compressed leaves name the packed byte layout; a leaf
+        packed in another layout is refused, never decoded wrongly."""
+        import json
+        from repro.comm.bits import LAYOUT
+        tree = {"master": jnp.linspace(-1, 1, 1000, dtype=jnp.float32),
+                "m": jnp.linspace(-2, 2, 1000, dtype=jnp.float32)}
+        d = store.save(str(tmp_path), tree, step=1, codec="uniform_amax:5")
+        out = store.restore(str(tmp_path), tree)
+        np.testing.assert_array_equal(np.asarray(out["master"]),
+                                      np.asarray(tree["master"]))
+        np.testing.assert_allclose(np.asarray(out["m"]),
+                                   np.asarray(tree["m"]), atol=2 / 32)
+        path = os.path.join(d, "manifest.json")
+        with open(path) as f:
+            manifest = json.load(f)
+        [ent] = [l for l in manifest["leaves"] if l.get("codec")]
+        assert ent["layout"] == LAYOUT
+        del ent["layout"]                      # as written before layouts
+        with open(path, "w") as f:
+            json.dump(manifest, f)
+        with pytest.raises(ValueError, match="wire layout"):
+            store.restore(str(tmp_path), tree)
+
     def test_crash_mid_save_keeps_previous(self, tmp_path, monkeypatch):
         """A crash while writing step 2 leaves step 1 intact and
         restorable - the manifest only becomes visible via the atomic
