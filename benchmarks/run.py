@@ -255,7 +255,6 @@ def bench_fleet(emit, n_requests=36, seed=0):
         sess.submit(Request(prompt=list(range(1, 21)), max_new_tokens=3))
         sess.drain()
         sess.stats["max_inflight"] = 0
-        sess.ttft_s.clear()
         sess._steps = 0
         t0 = time.perf_counter()
         it = iter(sched)
@@ -276,7 +275,7 @@ def bench_fleet(emit, n_requests=36, seed=0):
         res = sess.drain()             # finish everything in flight
         dt = time.perf_counter() - t0
         toks = sum(len(res[h].tokens) for h in submitted)
-        ttfts = sorted(sess.ttft_s.values())
+        ttfts = sorted(res[h].first_token_s for h in submitted)
         p99 = ttfts[min(len(ttfts) - 1, int(len(ttfts) * 0.99))]
         return dict(dt=dt, toks=toks, tok_s=toks / dt, p99=p99,
                     peak=sess.stats["max_inflight"],
@@ -898,7 +897,7 @@ def main() -> None:
     from repro.perf import profiling
     with profiling.trace(args.trace_dir, enabled=args.trace) as tdir:
         for n in names:
-            with profiling.annotate(f"bench:{n}"):
+            with profiling.span(f"bench:{n}"):
                 BENCHES[n](emit)
     if tdir:
         print(f"# trace: {tdir}", file=sys.stderr)

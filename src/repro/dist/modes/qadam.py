@@ -3,6 +3,7 @@ codes on the update-exchange wire (fused encode straight to payload
 rows - the codes never hit HBM unpacked)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro import comm
@@ -29,22 +30,25 @@ def make_updater(tc, ctx: WorkerCtx):
         # hierarchical: fp node-mean gradient first; the quantized
         # exchange below then ships one row per node over the slow tier.
         g = tier_grad_mean(g, tiers)
-        m2, v2, de = engine.adam_ef_moments(
-            g, m, v, e, a_t, tc.beta, th_t, tc.eps, backend=ctx.backend)
-        if tc.grad_k is None:
-            rows = SH.flatten_pad(de, ctx.n_workers)
-            recv = C.exchange_rows_tiered(rows, tiers)
-            e2 = jnp.zeros_like(e)
-        else:
-            scale = grids.amax_scale(de)
-            payload, e2 = comm.encode_rows_ef(de, scale, codec,
-                                              ctx.n_workers,
-                                              backend=ctx.backend)
-            if not tc.error_feedback:
+        with jax.named_scope("qadam.update"):
+            m2, v2, de = engine.adam_ef_moments(
+                g, m, v, e, a_t, tc.beta, th_t, tc.eps, backend=ctx.backend)
+        with jax.named_scope("qadam.exchange"):
+            if tc.grad_k is None:
+                rows = SH.flatten_pad(de, ctx.n_workers)
+                recv = C.exchange_rows_tiered(rows, tiers)
                 e2 = jnp.zeros_like(e)
-            recv = C.exchange_decode_tiered(payload, scale, codec, meta.c,
-                                            tiers, backend=ctx.backend)
-        return chunk - worker_mean(recv), m2, v2, e2
+            else:
+                scale = grids.amax_scale(de)
+                payload, e2 = comm.encode_rows_ef(de, scale, codec,
+                                                  ctx.n_workers,
+                                                  backend=ctx.backend)
+                if not tc.error_feedback:
+                    e2 = jnp.zeros_like(e)
+                recv = C.exchange_decode_tiered(payload, scale, codec,
+                                                meta.c, tiers,
+                                                backend=ctx.backend)
+            return chunk - worker_mean(recv), m2, v2, e2
     return upd
 
 

@@ -439,16 +439,19 @@ def make_train_step(model, mesh, tc: TrainConfig) -> StepArtifacts:
 
         # 1. weight broadcast: chunks -> Q_x(x_t) shards. broadcast_ef
         # modes thread the per-chunk server residual through the codec.
-        if mode.broadcast_ef:
-            srv = [x.reshape(m.c) for x, m in
-                   zip(treedef.flatten_up_to(state["es"]), metas_flat)]
-            pairs = [chunks_to_shard(ch, m, es)
-                     for ch, m, es in zip(masters, metas_flat, srv)]
-            new_es = [p[1] for p in pairs]
-        else:
-            pairs = [chunks_to_shard(ch, m)
-                     for ch, m in zip(masters, metas_flat)]
-            new_es = None
+        # The ``qadam.*`` scopes name the step's phases in every op's
+        # metadata (the backward shows as ``transpose(jvp(qadam.loss))``).
+        with jax.named_scope("qadam.broadcast"):
+            if mode.broadcast_ef:
+                srv = [x.reshape(m.c) for x, m in
+                       zip(treedef.flatten_up_to(state["es"]), metas_flat)]
+                pairs = [chunks_to_shard(ch, m, es)
+                         for ch, m, es in zip(masters, metas_flat, srv)]
+                new_es = [p[1] for p in pairs]
+            else:
+                pairs = [chunks_to_shard(ch, m)
+                         for ch, m in zip(masters, metas_flat)]
+                new_es = None
         xs = [p[0] for p in pairs]
         # fence the forward/backward off from the channel/update code so
         # XLA compiles it like a standalone value_and_grad: its float
@@ -469,7 +472,8 @@ def make_train_step(model, mesh, tc: TrainConfig) -> StepArtifacts:
         all_axes = worker_axes + maxes
 
         def lfn(pt):
-            s, nt = model.loss(pt, batch, ctx)
+            with jax.named_scope("qadam.loss"):
+                s, nt = model.loss(pt, batch, ctx)
             if tc.mode == "dp_adam":
                 # local sum / global count; the weight-gather transpose
                 # already sums model-axis contributions, the worker-axis

@@ -5,7 +5,8 @@ code-resident Q_x weights (the paper's 'Size' column, held as int codes).
       --requests 8 --slots 4 --max-new 16 --quantized
 
 Submitting more requests than slots exercises the scheduler: queued
-requests claim slots mid-flight as earlier ones finish.
+requests claim slots mid-flight as earlier ones finish. `--profile DIR`
+records the run's profile, with the session's `serve.*` spans, into DIR.
 """
 from __future__ import annotations
 
@@ -86,12 +87,19 @@ def build_parser():
     ap.add_argument("--aot-dir", default=None, metavar="DIR",
                     help="AOT artifact dir for the compiled decode step "
                          "(repro.perf.aot): warm restarts skip compilation")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="record a jax.profiler profile of the run into DIR")
     return ap
 
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    from repro.perf import profiling
+    with profiling.trace(args.profile, enabled=args.profile is not None):
+        _serve(args)
 
+
+def _serve(args):
     from repro import perf
     if not args.no_compile_cache:
         print(f"compile cache: {perf.enable_persistent_cache()}")
@@ -123,9 +131,12 @@ def main(argv=None):
     results = session.drain()
     dt = time.time() - t0
     total_new = sum(len(results[h].tokens) for h in handles)
+    first = [results[h].first_token_s for h in handles
+             if results[h].first_token_s is not None] or [float("nan")]
     print(f"generated {total_new} tokens over {args.requests} requests on "
           f"{args.slots} slots in {dt:.2f}s ({total_new / dt:.1f} tok/s); "
-          f"stats={session.stats}")
+          f"first token p50 {np.percentile(first, 50):.3f}s "
+          f"p95 {np.percentile(first, 95):.3f}s; stats={session.stats}")
     for i, h in enumerate(handles):
         r = results[h]
         print(f"  req{i}: {r.tokens[:12]}{'...' if len(r.tokens) > 12 else ''}"

@@ -22,7 +22,9 @@ restores the checkpointed bit plan and stats EMA.
 tier first, the quantized+EF exchange crosses only the node tier.
 `--multihost` initializes ``jax.distributed`` for one-process-per-host
 runs; CI simulates hosts with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
+``XLA_FLAGS=--xla_force_host_platform_device_count=N``. `--profile DIR`
+records the run's profile, with the session's `train.*` spans and the
+step's `qadam.*` scopes, into DIR.
 """
 from __future__ import annotations
 
@@ -236,6 +238,8 @@ def build_parser():
                     help="AOT step-artifact dir: restart/resume loads the "
                          "serialized compiled train step instead of "
                          "tracing+compiling (repro.perf.aot)")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="record a jax.profiler profile of the run into DIR")
     return ap
 
 
@@ -253,7 +257,12 @@ def main(argv=None):
         import jax
         jax.distributed.initialize(args.coordinator, args.num_processes,
                                    args.process_id)
+    from repro.perf import profiling
+    with profiling.trace(args.profile, enabled=args.profile is not None):
+        _train(ap, args)
 
+
+def _train(ap, args):
     import jax
     from repro import perf
     if not args.no_compile_cache:

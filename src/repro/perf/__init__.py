@@ -5,8 +5,8 @@ The subsystem that catches fused-kernel regressions at authoring time
 1.5x grace tolerated it) and eliminates jit cold-start on fleet
 restarts:
 
-  * :mod:`repro.perf.profiling` - ``jax.profiler`` trace harness with
-    per-bench annotations (``benchmarks/run.py --trace``);
+  * :mod:`repro.perf.profiling` - the program's spans and host-time
+    counters, and ``trace`` (the launchers' ``--profile DIR``);
   * :mod:`repro.perf.cache`     - persistent XLA compilation cache
     placement shared by the launchers;
   * :mod:`repro.perf.aot`       - ahead-of-time export/load of compiled
@@ -19,11 +19,11 @@ from repro.perf import aot, autotune, cache, profiling
 from repro.perf.aot import load_or_compile, step_key
 from repro.perf.cache import (cache_entries, disable_persistent_cache,
                               enable_persistent_cache)
-from repro.perf.profiling import annotate, trace
+from repro.perf.profiling import span, trace
 
 __all__ = [
     "aot", "autotune", "cache", "profiling",
-    "annotate", "trace",
+    "span", "trace",
     "cache_entries", "disable_persistent_cache", "enable_persistent_cache",
     "load_or_compile", "step_key",
 ]

@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 from typing import Callable, Dict, List, Optional, Sequence
 
@@ -59,6 +60,7 @@ import numpy as np
 
 from repro.models.layers import ShardCtx
 from repro.perf import aot
+from repro.perf.profiling import span
 from repro.serve.paged import PagePool
 from repro.serve.quantized import is_quantized, make_dequant_gather
 
@@ -67,6 +69,26 @@ from repro.serve.quantized import is_quantized, make_dequant_gather
 SLO_PRIORITY = {"batch": 0, "standard": 1, "interactive": 2}
 
 _PAGED_LEAVES = ("pk", "pv", "ptab")
+
+
+def _session_call(method):
+    """A public session method. While the device has no step queued -
+    from a sync's return to the next decode or prefill dispatch - the
+    time spent inside such methods goes to ``stats["idle_host_ns"]``; the
+    caller's own time between calls is never counted."""
+    @functools.wraps(method)
+    def call(self, *args, **kwargs):
+        if self._in_call:
+            return method(self, *args, **kwargs)
+        self._in_call = True
+        if self._idle:
+            self._idle_t0 = time.perf_counter_ns()
+        try:
+            return method(self, *args, **kwargs)
+        finally:
+            self._in_call = False
+            self._count_idle(dispatched=False)
+    return call
 
 
 def _raw_key(key: jax.Array) -> jax.Array:
@@ -96,6 +118,9 @@ class Result:
     handle: int = -1
     # "length" | "eos" | "cache_full" | "preempted"
     finish_reason: str = "length"
+    # submit -> return of the first sync after which the host held the
+    # request's first token (None if it never had one)
+    first_token_s: Optional[float] = None
 
 
 class ServeSession:
@@ -134,6 +159,21 @@ class ServeSession:
         geometry, sample mode, quantization, arg signature). A warm dir
         makes the first dispatch skip trace+lower+compile; local decode
         path only. ``stats`` records ``compilations`` vs ``aot_loads``.
+
+    ``stats`` holds integer counters that only grow: ``dispatches``
+    (decode steps), ``chunk_dispatches``, ``syncs``, ``admitted`` and
+    ``preemptions``; ``sync_drain_ns``, the host's wait in a sync for the
+    dispatched steps, and ``fetch_ns``, its copy of the slots' rows after
+    that (the device is idle then); ``idle_host_ns``, the time inside the
+    session's public methods from a sync's return to the next decode or
+    prefill dispatch; and ``decode_tokens``, the tokens decode dispatches
+    emitted, counted at each sync from every slot's change in ``gen``
+    since the previous one (a prefill's first token is not counted;
+    tokens of a request preempted and requeued between two syncs are not
+    counted either). The phases are ``repro.perf.profiling`` spans named
+    ``serve.<phase>``: ``submit``, ``schedule``, ``prefill_chunk``,
+    ``decode``, ``harvest``, ``sync.drain``, ``sync.fetch``, ``collect``
+    and ``first_token``; per-request ones carry the request's handle.
     """
 
     def __init__(self, model, params, *, slots: int = 8, max_seq: int = 256,
@@ -223,15 +263,25 @@ class ServeSession:
         self._requests: Dict[int, Request] = {}
         self._req_key: Dict[int, jax.Array] = {}   # stable across preemption
         self._results: Dict[int, Result] = {}
-        self._submit_t: Dict[int, float] = {}
-        self.ttft_s: Dict[int, float] = {}  # submit -> first-token dispatch
+        # per-request timing, held only while the request is in flight:
+        # its submit stamp until the host holds its first token, then the
+        # first-token time until its result is made
+        self._submit_ns: Dict[int, int] = {}
+        self._first_token_s: Dict[int, float] = {}
+        # each slot's ``gen`` as the last sync saw it (or as its admission
+        # set it): the base from which decode tokens are counted
+        self._gen_seen = [0] * slots
+        # idle accounting (see _session_call): set by a sync, cleared by
+        # the next decode or prefill dispatch
+        self._idle, self._idle_t0, self._in_call = False, 0, False
         self._next_handle = 0
         self._admit_seq = 0             # submissions since the last reseed
         self._steps = 0
         self.stats = {"dispatches": 0, "syncs": 0, "admitted": 0,
                       "compilations": 0, "aot_loads": 0,
                       "preemptions": 0, "chunk_dispatches": 0,
-                      "max_inflight": 0}
+                      "max_inflight": 0, "sync_drain_ns": 0, "fetch_ns": 0,
+                      "idle_host_ns": 0, "decode_tokens": 0}
 
     # ------------------------------------------------------------------
     # device-side state + compiled programs
@@ -534,6 +584,7 @@ class ServeSession:
         # the last (which is emitted, never fed back)
         return self._pool.pages_for(len(req.prompt) + req.max_new_tokens - 1)
 
+    @_session_call
     def submit(self, req: Request) -> int:
         """Queue a request; returns its handle. Claims a free slot
         immediately when one is available (preempting a lower SLO class
@@ -554,17 +605,18 @@ class ServeSession:
                 f"holds {self.num_pages}")
         h = self._next_handle
         self._next_handle += 1
-        self._requests[h] = req
-        # fold on the submission ordinal since the last (re)seed: identical
-        # (requests, key) sequences after a reseed() draw identical
-        # sampling streams, and the key survives preemption-requeue so a
-        # resumed request replays its exact draws
-        self._req_key[h] = jax.random.fold_in(self._base_key,
-                                              self._admit_seq)
-        self._admit_seq += 1
-        self._submit_t[h] = time.perf_counter()
-        self._enqueue(h)
-        self._schedule()
+        with span("serve.submit", handle=h):
+            self._submit_ns[h] = time.perf_counter_ns()
+            self._requests[h] = req
+            # fold on the submission ordinal since the last (re)seed:
+            # identical (requests, key) sequences after a reseed() draw
+            # identical sampling streams, and the key survives
+            # preemption-requeue so a resumed request replays its draws
+            self._req_key[h] = jax.random.fold_in(self._base_key,
+                                                  self._admit_seq)
+            self._admit_seq += 1
+            self._enqueue(h)
+            self._schedule()
         return h
 
     def _enqueue(self, h: int):
@@ -584,18 +636,21 @@ class ServeSession:
         allow. Under pressure, first collect any already-finished slots
         (so a completed request is never "preempted"), then preempt
         strictly-lower-SLO occupants."""
-        while self._pending:
-            h = self._pending[0]
-            req = self._requests[h]
-            if self._try_admit(h, req):
-                self._pending.pop(0)
-                continue
-            if allow_harvest and self.inflight:
-                allow_harvest = False
-                if self._collect_finished():
+        if not self._pending:
+            return
+        with span("serve.schedule"):
+            while self._pending:
+                h = self._pending[0]
+                req = self._requests[h]
+                if self._try_admit(h, req):
+                    self._pending.pop(0)
                     continue
-            if not self._try_preempt_for(req):
-                break
+                if allow_harvest and self.inflight:
+                    allow_harvest = False
+                    if self._collect_finished():
+                        continue
+                if not self._try_preempt_for(req):
+                    break
 
     def _try_admit(self, handle: int, req: Request) -> bool:
         free = [s for s, owner in enumerate(self._slot_handle)
@@ -655,8 +710,9 @@ class ServeSession:
                 self._results[h] = Result(
                     tokens=[int(t) for t in snap["out"][slot, :n]],
                     prompt_len=len(req.prompt), handle=h,
-                    finish_reason="preempted")
-            self._req_key.pop(h, None)
+                    finish_reason="preempted",
+                    first_token_s=self._first_token_s.get(h))
+            self._forget(h)
         else:
             # requeue-and-recompute: the request (and its sampling key)
             # goes back to the head of its SLO class
@@ -689,6 +745,9 @@ class ServeSession:
             ptab_row = jnp.zeros((1,), jnp.int32)  # unused placeholder
         self._slot_handle[slot] = handle
         mode = self._admission_mode(plen)
+        # whole prefill leaves gen at 1 (its first token); staging and
+        # injection at 0
+        self._gen_seen[slot] = int(mode == "whole")
         if mode == "whole":
             fn = self._prefill_fns.get(plen)
             if fn is None:
@@ -699,6 +758,7 @@ class ServeSession:
                 jnp.asarray(np.asarray(req.prompt, np.int32)),
                 jnp.int32(req.max_new_tokens),
                 jnp.float32(req.temperature), key)
+            self._count_idle(dispatched=True)
             self._finalize_admission(slot, handle, req,
                                      remaining=req.max_new_tokens - 1)
         elif mode == "chunked":
@@ -731,9 +791,6 @@ class ServeSession:
         self._slot_done_step[slot] = self._steps + remaining
         if req.temperature > 0:
             self._hot.add(handle)
-        if handle not in self.ttft_s and handle in self._submit_t:
-            self.ttft_s[handle] = (time.perf_counter()
-                                   - self._submit_t[handle])
 
     def _chunk_fn(self, is_last: bool) -> Callable:
         fn = self._chunk_fns.get(is_last)
@@ -756,13 +813,17 @@ class ServeSession:
         tok[:hi - lo] = pp["tokens"][lo:hi]
         is_last = hi >= pp["plen"]
         fn = self._chunk_fn(is_last)
-        self._state = fn(self.params, self._state, jnp.int32(slot),
-                         jnp.asarray(tok), jnp.int32(lo),
-                         jnp.int32(hi - lo), jnp.int32(pp["max_new"]),
-                         jnp.float32(pp["temp"]), pp["key"])
+        with span("serve.prefill_chunk", handle=pp["handle"],
+                  is_last=is_last):
+            self._state = fn(self.params, self._state, jnp.int32(slot),
+                             jnp.asarray(tok), jnp.int32(lo),
+                             jnp.int32(hi - lo), jnp.int32(pp["max_new"]),
+                             jnp.float32(pp["temp"]), pp["key"])
+        self._count_idle(dispatched=True)
         pp["next"] = hi
         self.stats["chunk_dispatches"] += 1
         if is_last:
+            self._gen_seen[slot] = 1       # the final chunk's first token
             del self._prefill_q[slot]
             h = pp["handle"]
             self._finalize_admission(
@@ -801,6 +862,7 @@ class ServeSession:
             return fn
         return fn.lower(self.params, self._state).compile()
 
+    @_session_call
     def step(self):
         """One decode step for every slot (a single device dispatch),
         preceded by at most one chunked-prefill dispatch. While the
@@ -810,7 +872,9 @@ class ServeSession:
         slots mid-flight without a per-token host sync."""
         self._advance_prefill()
         fn = self._step_callable(bool(self._hot))
-        self._state = fn(self.params, self._state)
+        with span("serve.decode"):
+            self._state = fn(self.params, self._state)
+        self._count_idle(dispatched=True)
         self.stats["dispatches"] += 1
         self._steps += 1
         if self._pending:
@@ -822,11 +886,40 @@ class ServeSession:
                     and self._steps % self.sync_interval == 0):
                 self.harvest()
 
-    def _sync(self):
-        self.stats["syncs"] += 1
-        keys = ("active", "gen", "plen", "out")
-        return jax.device_get({k: self._state[k] for k in keys})
+    def _count_idle(self, dispatched: bool):
+        """Charge the idle time since ``_idle_t0`` (see _session_call);
+        a decode or prefill dispatch ends the idle stretch."""
+        if self._idle:
+            self.stats["idle_host_ns"] += (time.perf_counter_ns()
+                                           - self._idle_t0)
+            self._idle = not dispatched
 
+    def _sync(self):
+        """Wait for every dispatched step, then copy the slots' control
+        rows to the host; count the decode tokens and stamp the first
+        tokens that the copy brings."""
+        self.stats["syncs"] += 1
+        self._count_idle(dispatched=False)
+        rows = {k: self._state[k] for k in ("active", "gen", "plen", "out")}
+        with span("serve.sync.drain", self.stats, "sync_drain_ns"):
+            jax.block_until_ready(rows)
+        with span("serve.sync.fetch", self.stats, "fetch_ns"):
+            snap = jax.device_get(rows)
+        now = time.perf_counter_ns()
+        self._idle, self._idle_t0 = True, now
+        for s, h in enumerate(self._slot_handle):
+            if h is None:
+                continue
+            g = int(snap["gen"][s])
+            self.stats["decode_tokens"] += g - self._gen_seen[s]
+            self._gen_seen[s] = g
+            if g and h in self._submit_ns:
+                self._first_token_s[h] = (now - self._submit_ns.pop(h)) / 1e9
+                with span("serve.first_token", handle=h):
+                    pass
+        return snap
+
+    @_session_call
     def harvest(self) -> List[int]:
         """Collect finished slots into results, free them (returning their
         pages to the pool), and admit queued requests. Returns the handles
@@ -835,29 +928,39 @@ class ServeSession:
         self._schedule(allow_harvest=False)
         return finished
 
+    def _forget(self, h: int):
+        """Drop a finished request's key and timing."""
+        self._req_key.pop(h, None)
+        self._submit_ns.pop(h, None)
+        self._first_token_s.pop(h, None)
+
     def _collect_finished(self) -> List[int]:
-        snap = self._sync()
-        finished = []
-        for s in range(self.slots):
-            h = self._slot_handle[s]
-            if h is None or snap["active"][s] or s in self._prefill_q:
-                continue
-            n = int(snap["gen"][s])
-            req = self._requests.pop(h)   # bounded host state: one entry
-            reason = "length"             # per in-flight request only
-            if n < req.max_new_tokens:
-                reason = ("eos" if self.eos_id is not None
-                          and n > 0 and int(snap["out"][s, n - 1]) == self.eos_id
-                          else "cache_full")
-            self._results[h] = Result(
-                tokens=[int(t) for t in snap["out"][s, :n]],
-                prompt_len=int(snap["plen"][s]), handle=h,
-                finish_reason=reason)
-            self._req_key.pop(h, None)
-            self._free_slot(s, release=self.paged)
-            finished.append(h)
+        with span("serve.harvest"):
+            snap = self._sync()
+            finished = []
+            with span("serve.collect"):
+                for s in range(self.slots):
+                    h = self._slot_handle[s]
+                    if h is None or snap["active"][s] or s in self._prefill_q:
+                        continue
+                    n = int(snap["gen"][s])
+                    req = self._requests.pop(h)   # bounded host state: one
+                    reason = "length"             # per in-flight request
+                    if n < req.max_new_tokens:
+                        reason = ("eos" if self.eos_id is not None and n > 0
+                                  and int(snap["out"][s, n - 1]) == self.eos_id
+                                  else "cache_full")
+                    self._results[h] = Result(
+                        tokens=[int(t) for t in snap["out"][s, :n]],
+                        prompt_len=int(snap["plen"][s]), handle=h,
+                        finish_reason=reason,
+                        first_token_s=self._first_token_s.get(h))
+                    self._forget(h)
+                    self._free_slot(s, release=self.paged)
+                    finished.append(h)
         return finished
 
+    @_session_call
     def drain(self, max_steps: Optional[int] = None) -> Dict[int, Result]:
         """Step until every submitted request has finished; returns the
         results not yet delivered as ``{handle: Result}``. Results are

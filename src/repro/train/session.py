@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.checkpoint import store
 from repro.perf import aot
+from repro.perf.profiling import span
 
 
 @dataclasses.dataclass
@@ -332,7 +333,11 @@ class TrainSession:
     its OWN entry pinned to its own step - the old loop misattached evals
     to the most recent log entry). ``stats`` mirrors ``ServeSession``:
     ``dispatches`` (compiled step calls), ``syncs`` (host device_gets on
-    the critical path - zero in steady state), ``steps``, ``ckpts``.
+    the critical path - zero in steady state), ``steps``, ``ckpts``, and
+    ``batch_wait_ns``, the host's wait for the prefetcher's next batch.
+    The phases are ``repro.perf.profiling`` spans: ``train.batch_wait``,
+    ``train.dispatch``, ``train.harvest`` (the loss-ring sync) and
+    ``train.checkpoint``.
     """
 
     def __init__(self, program, batches: Iterator,
@@ -389,7 +394,7 @@ class TrainSession:
         # session built vs loaded ready-made (tests assert a warm AOT dir
         # means a zero-compilation session)
         self.stats = {"dispatches": 0, "syncs": 0, "steps": 0, "ckpts": 0,
-                      "compilations": 0, "aot_loads": 0}
+                      "compilations": 0, "aot_loads": 0, "batch_wait_ns": 0}
         self._ckpt_q: Optional[queue.Queue] = None
         self._ckpt_thread: Optional[threading.Thread] = None
         self._ckpt_err: Optional[BaseException] = None
@@ -516,7 +521,8 @@ class TrainSession:
         ring segments."""
         if not self._segments:
             return []
-        vals = self._sync(self._ring)
+        with span("train.harvest"):
+            vals = self._sync(self._ring)
         out = []
         for first, slot, k in self._segments:
             for j in range(k):
@@ -599,19 +605,20 @@ class TrainSession:
         if not self.cfg.ckpt_dir:
             raise ValueError("SessionConfig.ckpt_dir is not set")
         step = self._step if step is None else step
-        # device-side copy: the live buffers are donated into the next
-        # dispatch, the snapshot stays valid for the writer
-        snap = jax.tree.map(jnp.copy, self._state)
-        tree = self._program.to_ckpt(snap)
-        extra = {"batches_consumed": self._step, **self.ckpt_extra}
-        self.stats["ckpts"] += 1
-        if self.cfg.ckpt_async:
-            self._ensure_writer()
-            self._ckpt_q.put((tree, step, extra))
-        else:
-            store.save(self.cfg.ckpt_dir, tree, step=step,
-                       keep=self.cfg.ckpt_keep, extra=extra,
-                       codec=self.cfg.ckpt_codec)
+        with span("train.checkpoint", step=step):
+            # device-side copy: the live buffers are donated into the next
+            # dispatch, the snapshot stays valid for the writer
+            snap = jax.tree.map(jnp.copy, self._state)
+            tree = self._program.to_ckpt(snap)
+            extra = {"batches_consumed": self._step, **self.ckpt_extra}
+            self.stats["ckpts"] += 1
+            if self.cfg.ckpt_async:
+                self._ensure_writer()
+                self._ckpt_q.put((tree, step, extra))
+            else:
+                store.save(self.cfg.ckpt_dir, tree, step=step,
+                           keep=self.cfg.ckpt_keep, extra=extra,
+                           codec=self.cfg.ckpt_codec)
 
     def wait_for_checkpoints(self):
         """Block until every queued async checkpoint hit disk."""
@@ -675,7 +682,8 @@ class TrainSession:
         run_start = self._step
         t0 = time.perf_counter()
         for di, k in enumerate(plan):
-            batch = self._prefetch.get(k)
+            with span("train.batch_wait", self.stats, "batch_wait_ns"):
+                batch = self._prefetch.get(k)
             if self._slot + k > self._ring_len:
                 self._slot = 0
             sl, i0 = self._slot, self._step
@@ -684,7 +692,8 @@ class TrainSession:
             else:
                 args = (self._state, self._ring, self._sring, sl, batch)
             self._last_dispatch = (k, batch)
-            out = self._built_step(k, args)(*args)
+            with span("train.dispatch", step=i0 + 1):
+                out = self._built_step(k, args)(*args)
             if self._sring is None:
                 self._state, self._ring = out
             else:

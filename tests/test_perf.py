@@ -233,7 +233,7 @@ class TestTraceHarness:
         d = str(tmp_path / "tr")
         with perf.trace(d) as out:
             assert out == d
-            with perf.annotate("bench:test"):
+            with perf.span("bench:test"):
                 jax.jit(lambda x: x @ x)(jnp.ones((32, 32))
                                          ).block_until_ready()
         runs = perf.profiling.trace_runs(d)
